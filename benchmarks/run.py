@@ -19,6 +19,7 @@ from benchmarks import (bench_communication, bench_ensemble, bench_extreme,
                         bench_fault, bench_hotswap, bench_kernels, bench_obs,
                         bench_prediction, bench_roofline, bench_serving,
                         bench_serving_mesh, bench_speedup, common)
+from repro.launch.compile_cache import enable_compile_cache
 
 ALL = [
     ("prediction", bench_prediction),    # paper Figs. 5-10
@@ -52,6 +53,7 @@ def main() -> None:
                     help="write each suite's rows to BENCH_<suite>.json "
                     "(per-phase name/us/metric)")
     args = ap.parse_args()
+    enable_compile_cache()
     failures = 0
     for name, mod in ALL:
         if args.only and args.only not in name:

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _ssd_kernel(xd_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
                 state_scr, *, chunk: int):
@@ -93,7 +91,7 @@ def ssd_pallas(xd, a, B_, C_, *, chunk: int = 128, interpret: bool = True):
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xd, a, B_, C_)

@@ -82,6 +82,9 @@ def main(argv: list[str] | None = None) -> None:
                     "live time-series view of serve-under-churn")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.configs.paper_lstm import CONFIG
     from repro.data import load_stock, make_windows, train_test_split
     from repro.models.rnn import init_rnn

@@ -25,7 +25,7 @@ many-client traffic trace against it.
     # synthetic labels
     PYTHONPATH=src python -m repro.launch.serve --checkpoint ckpt.npz
 
-    # host a zoo arch (reduced, CPU) serving next-token forecasts
+    # host a zoo arch (reduced config) serving next-token forecasts
     PYTHONPATH=src python -m repro.launch.serve --model qwen1.5-4b \
         --requests 128 --prompt-len 32
 """
@@ -140,6 +140,9 @@ def main(argv: list[str] | None = None) -> None:
                     "phase into DIR (view with TensorBoard / Perfetto)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.obs import EventLog, MetricsServer, Tracer
     from repro.serving import (BatcherConfig, CheckpointDaemon,
                                DurableStore, ModelRegistry,

@@ -43,7 +43,10 @@ def main(argv=None) -> int:
                          "checkpoint before the router re-adopts it")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.transport import serve_shard
+
+    enable_compile_cache()
 
     def _report(port: int) -> None:
         # machine-greppable: launch scripts scrape the bound port

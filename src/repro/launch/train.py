@@ -2,7 +2,7 @@
 
 Two modes:
   * ``--arch paper-lstm`` (default): the paper's experiment — async local
-    SGD on stock windows, n workers, linear schedule (runs on host CPU).
+    SGD on stock windows, n workers, linear schedule.
   * ``--arch <zoo id>``: train a (reduced or full) transformer config on
     synthetic tokens on whatever devices exist, using the same local-SGD
     round machinery (workers = data shards of the host mesh).
@@ -120,7 +120,7 @@ def run_zoo(args) -> None:
     assert np.isfinite(losses[-1])
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-lstm")
     ap.add_argument("--ticker", default="AAPL")
@@ -140,7 +140,14 @@ def main() -> None:
                     help="save the trained paper model as a serving "
                     "checkpoint (EVT-calibrated, version metadata)")
     ap.add_argument("--reduced", action="store_true")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv: list[str] | None = None) -> None:
+    args = parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.arch == "paper-lstm":
         run_paper_lstm(args)
     else:

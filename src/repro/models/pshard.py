@@ -19,13 +19,17 @@ import contextlib
 import threading
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 _CTX = threading.local()
 
 
 def set_context(mesh, batch_axes) -> None:
-    _CTX.mesh = mesh
+    # ``with_sharding_constraint`` accepts only Auto mesh axes, and
+    # ``jax.make_mesh`` builds Explicit ones: the constraints here are
+    # hints to sharding propagation, so view the mesh with Auto axes
+    _CTX.mesh = mesh.update(
+        axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     _CTX.batch = batch_axes
 
 

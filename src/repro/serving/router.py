@@ -287,10 +287,13 @@ class ShardedServingEngine:
             shard.spill_sessions()
             if shard._session_cache is not None:
                 for cid, carry, nbytes, version in shard.sessions.export():
-                    target = self.shards.get(self.router.shard_for(cid))
+                    tid = self.router.shard_for(cid)
+                    target = self.shards.get(tid)
                     if target is not None:
-                        target.sessions.put_new(cid, carry, nbytes,
-                                                version=version)
+                        # the carry moves to the new owner's device
+                        target.sessions.put_new(
+                            cid, self.swarm.place(tid, carry), nbytes,
+                            version=version)
             self.swarm.remove_replica(sid)
 
     def predict(self, model_key: str, window,

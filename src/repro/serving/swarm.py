@@ -321,6 +321,15 @@ class ShardSwarm:
         specs = jax.tree.map(lambda _: sharding, params)
         return jax.device_put(params, specs)
 
+    def place(self, shard_id: int, tree: PyTree) -> PyTree:
+        """``tree`` on ``shard_id``'s device — how state that changes
+        owner (a migrating session carry) joins the shard's programs,
+        which reject arrays committed to another device. Unchanged when
+        replicas share buffers (``transfer="reference"``)."""
+        if self._transfer != "device":
+            return tree
+        return self._transfer_params(tree, int(shard_id))
+
     def propagate(self, key: str | None = None) -> int:
         """Pull every replica up to the primary's newest version for
         ``key`` (or for all keys): the freshness sweep, beyond what the

@@ -862,6 +862,19 @@ class RemoteShard:
                 self.process.join(timeout)
 
 
+def _refuse_on_tpu() -> None:
+    """A TPU chip belongs to one process: a parent whose backend is the
+    TPU holds its chips, so a spawned shard worker would fail on
+    libtpu's lock or hang waiting for it. Refuse before spawning."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "local shard worker processes cannot share the TPU with this "
+            "process (one process per chip): serve from one process with "
+            "ShardedServingEngine, which places one replica on each chip")
+
+
 def spawn_shard(shard_id: int, config: BatcherConfig | None = None,
                 ctx=None, host: str = "127.0.0.1",
                 max_sessions: int = 4096,
@@ -869,7 +882,9 @@ def spawn_shard(shard_id: int, config: BatcherConfig | None = None,
     """Start one shard worker process locally and connect to it — the
     single-machine convenience path over the same ``hello`` handshake a
     remote worker speaks. The child binds an ephemeral port and reports
-    it back over a pipe before accepting the router's connection."""
+    it back over a pipe before accepting the router's connection.
+    Refused when this process runs on a TPU (one process per chip)."""
+    _refuse_on_tpu()
     ctx = ctx or mp.get_context("spawn")
     parent_pipe, child_pipe = ctx.Pipe()
     proc = ctx.Process(target=_worker_main, args=(child_pipe, host),
@@ -930,7 +945,10 @@ def connect_shard(addr, shard_id: int = 0,
 class MultiProcessServingEngine:
     """The sharded serving mesh over OS processes (and hosts): the
     ``ShardedServingEngine`` API, with every shard an ``EngineShard``
-    worker process behind the socket transport.
+    worker process behind the socket transport. A process whose backend
+    is the TPU cannot spawn local workers (one process per chip), so
+    construction refuses there; ``ShardedServingEngine`` is the mesh for
+    a TPU host.
 
     ``registry`` is the PRIMARY (defaults to a fresh ``ModelRegistry``):
     publishes against it — ``register`` / ``swap`` / ``load``, e.g. a
@@ -969,6 +987,7 @@ class MultiProcessServingEngine:
             raise ValueError("heartbeat_s must be > 0")
         if miss_budget < 1:
             raise ValueError("miss_budget must be >= 1")
+        _refuse_on_tpu()      # start() spawns n_shards local workers
         self.registry = registry if registry is not None else ModelRegistry()
         self.config = config or BatcherConfig()
         # router-side tracer (repro.obs.Tracer | None): traces started

@@ -225,6 +225,40 @@ def test_dispatched_cell_matches_ref_both_impls():
     np.testing.assert_allclose(got_p[1], want[1], rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("workers", [None, 2])
+def test_paper_loss_grad_pallas_matches_xla(workers):
+    """``value_and_grad`` of the paper loss through the forced Pallas
+    cell (interpret mode: kernel forward, ``custom_vjp`` backward)
+    matches the XLA path, alone and under ``vmap`` over workers — the
+    shape of ``local_sgd_round``."""
+    from repro.configs.paper_lstm import CONFIG
+    from repro.kernels import dispatch
+    from repro.models.rnn import init_rnn
+    from repro.training.loop import make_loss_fn
+
+    B = 8
+    loss_fn = make_loss_fn(CONFIG, evl_weight=0.5, beta0=0.9, beta1=0.1)
+    params = init_rnn(jax.random.PRNGKey(3), CONFIG)
+    batch = (RNG.standard_normal((B, CONFIG.window, CONFIG.input_dim)),
+             RNG.standard_normal(B), (RNG.uniform(size=B) < 0.3) * 1.0,
+             np.ones(B))
+    batch = tuple(jnp.asarray(a, jnp.float32) for a in batch)
+    fn = jax.value_and_grad(loss_fn)
+    if workers:
+        params = jax.tree.map(lambda a: jnp.stack([a] * workers), params)
+        batch = tuple(jnp.stack([a * (1 + w) for w in range(workers)])
+                      for a in batch)
+        fn = jax.vmap(fn)
+    dispatch.reset_table()
+    want_loss, want_grads = jax.jit(lambda p, b: fn(p, b))(params, batch)
+    with dispatch.force("pallas"):
+        got_loss, got_grads = jax.jit(lambda p, b: fn(p, b))(params, batch)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5, atol=1e-6)
+    for g, w in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-6)
+
+
 def test_model_cell_routes_through_dispatch(monkeypatch):
     """``models.rnn.lstm_cell`` consults the dispatch layer — forcing
     Pallas must reach the kernel wrapper."""

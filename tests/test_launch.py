@@ -108,3 +108,30 @@ def test_roofline_terms_math():
     assert abs(r["collective_s"] - 1.0) < 1e-6
     assert r["dominant"] in ("compute", "memory", "collective")
     assert r["model_flops"] == 6 * cfg.active_param_count() * 256 * 4096
+
+
+def test_compile_cache_follows_env(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert updates == []          # JAX reads the variable itself
+
+
+def test_compile_cache_fixed_checkout_path(monkeypatch):
+    import os
+
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert compile_cache.compile_cache_dir() == want     # never moves
+    assert updates == [("jax_compilation_cache_dir", want)]

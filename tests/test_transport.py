@@ -466,3 +466,19 @@ def test_socket_steps_fuse_into_batched_decode(forecaster):
         assert counts["slots_insert"] == n     # one lane entry per client
         assert counts["decode_many"] == 0      # no host gather/scatter
         assert counts["decode_step"] == 0      # nothing went per-session
+
+
+def test_process_mesh_refuses_on_tpu(monkeypatch):
+    """A parent on the TPU holds its chips: spawned shard workers could
+    not open them, so the process mesh refuses before spawning."""
+    from repro.serving import transport
+
+    spawned = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(transport.mp, "get_context",
+                        lambda *a: spawned.append(a))
+    with pytest.raises(RuntimeError, match="ShardedServingEngine"):
+        transport.spawn_shard(0, BCFG)
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        MultiProcessServingEngine(ModelRegistry(), BCFG, n_shards=2)
+    assert spawned == []
