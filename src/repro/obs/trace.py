@@ -1,12 +1,25 @@
-"""Per-request trace spans for the serving stack: stdlib-only,
-thread-safe, cheap enough for the flush hot path.
+"""Spans for the serving stack and the trainer, on two clocks.
+
+``span(name)`` opens a host span on the profiler's clock: it is
+``jax.profiler.TraceAnnotation`` (jax imported on first use, so this
+module stays importable without it). With no profiler running its cost
+is the inactive TraceMe check, under a microsecond. A profiler trace
+holds these spans on the same clock as the device's operations, so they
+are what attributes the device's idle gaps to what the host was doing.
+``SPANS`` names every span the program opens, with what it covers.
+
+The ``Tracer`` ring below keeps per-request spans on its own clock,
+``now()``: ``perf_counter`` anchored to the epoch once at import. That
+clock is comparable across the processes of one machine (a request
+stitched over the socket transport) but is not the profiler's, which
+differs from it by a constant, so ring spans cannot be placed against
+device gaps. The ring is for per-request views (queue wait, a request's
+path across processes); ``span`` is for gap attribution.
 
 A trace is born at ``Tracer.start`` (one per request), accumulates
 spans as the request moves ``submit -> queue -> flush -> gather ->
 dispatch -> scatter -> reply``, and is ``finish``-ed into a bounded
-ring of completed traces. Spans are plain (t0, t1) wall-clock pairs —
-``now()`` is a monotonic ``perf_counter`` anchored to the epoch once at
-import.
+ring of completed traces. Spans are plain (t0, t1) pairs on ``now()``.
 
 The recording path is built around the same amortization as the engine
 it observes:
@@ -74,6 +87,55 @@ _perf_counter = time.perf_counter
 def now() -> float:
     """Wall-clock seconds from a monotonic source (see ``_EPOCH``)."""
     return _EPOCH + _perf_counter()
+
+
+# every span the program opens with ``span``, and what it covers
+SPANS = {
+    "repro.engine.wait":
+        "the flush thread blocked on its queue, waiting for traffic",
+    "repro.engine.flush.predict":
+        "one predict flush, from its running requests to the last reply",
+    "repro.engine.flush.step":
+        "one step flush, from its running requests to the last reply",
+    "repro.engine.gather":
+        "a predict flush's weights lookup and right-padded batch",
+    "repro.engine.reply":
+        "a flush's telemetry, results to the futures and trace finish",
+    "repro.predict": "the jitted predict call (launch, not its wait)",
+    "repro.readback":
+        "the host waiting for and copying a forecast and alert back",
+    "repro.decode_step": "one single-chunk decode step call",
+    "repro.decode_many": "one batched gather/scatter decode call",
+    "repro.decode_replay": "one full-window replay (prefill) call",
+    "repro.slots_insert": "a carry written into a decode lane",
+    "repro.slots_extract": "a carry read out of a decode lane",
+    "repro.slots_generate": "one fused generate over the decode lanes",
+    "repro.session_step_many":
+        "a session runner's batched step: lanes, generate, readback",
+    "repro.sessions.lane_miss":
+        "one lane insert: the spill tier or history, the LRU lane's "
+        "extract and spill, the insert",
+    "repro.round": "one local-SGD round of the trainer",
+    "repro.round.local":
+        "the call of the round's local-steps program, with the copy of "
+        "its batches to the device",
+    "repro.round.exchange":
+        "the model exchange: worker mean and broadcast, or the stale "
+        "average applied",
+    "repro.round.loss": "the host waiting for the round's mean loss",
+}
+
+_annotation = None
+
+
+def span(name: str):
+    """A host span ``name`` (a key of ``SPANS``) on the profiler's
+    clock, as a context manager: ``with span("repro.round"): ...``."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
 
 
 # trace ids must be unique across the router and worker processes that
@@ -527,12 +589,6 @@ class Tracer:
                 else:
                     out.extend(e.materialize(self))
         return out if n is None else out[-n:]
-
-    def find(self, trace_id: str) -> Trace | None:
-        for t in reversed(self.traces()):
-            if t._tid == trace_id:
-                return t
-        return None
 
     def last(self) -> Trace | None:
         out = self.traces(1)

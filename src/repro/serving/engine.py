@@ -31,6 +31,7 @@ from concurrent.futures import Future
 
 import numpy as np
 
+from repro.obs import trace as obs_trace
 from repro.obs.trace import _EPOCH as _TRACE_EPOCH
 from repro.obs.trace import FlushSpans as _FlushSpans
 from repro.obs.trace import finish_all as _finish_all
@@ -693,9 +694,6 @@ class EngineShard:
         """One batched decode flush: every queued step for ``model_key``
         becomes one fused dispatch per decode-lane chunk via the
         session runner's gather/scatter ``step_many``."""
-        reqs = [r for r in reqs if r.future.set_running_or_notify_cancel()]
-        if not reqs:
-            return
         tracer = self.tracer
         traced, deferred, fspans = self._trace_gather(tracer, reqs)
         if fspans is not None:
@@ -713,72 +711,80 @@ class EngineShard:
                 tracer.finish_block("step", self._meta_for(model_key),
                                     fspans, deferred, status="error")
             return
-        if fspans is not None:
-            fspans.stamp("dispatch")
-        now = time.perf_counter()
-        version = getattr(fc, "version", None)
-        # lane slots actually dispatched (waves for duplicate clients,
-        # each padded to the decode width) — counted by the runner at
-        # the dispatch decision, not re-derived here
-        padded = getattr(runner, "last_step_slots", len(reqs))
-        self.telemetry.record_step_batch([now - r.t_enq for r in reqs],
-                                         n_padded=padded,
-                                         model=model_key)
-        spec = self._ensemble_spec(model_key)
-        if spec is not None:
-            # the fuse happened inside the ensemble runtime's step_many;
-            # surface its alert/anomaly outcome into telemetry and the
-            # flush worker's max_wait multipliers
-            rt = self._ensemble(model_key)
-            thr = rt.fuser().alert_threshold()
-            self._note_anomaly(model_key, spec, rt)
-            self.telemetry.record_ensemble(
-                alerts=sum(1 for _, p in outs if p >= thr), n=len(outs),
-                anomaly=rt.fuser().anomaly)
-        if fspans is not None:
-            # scatter + the umbrella flush span BEFORE set_result: the
-            # transport worker's done-callback exports the trace, so
-            # anything after delivery would be lost cross-process
-            fspans.umbrella("flush", t0f, fspans.stamp("scatter"))
-        for r, (y, p) in zip(reqs, outs):
-            r.future.model_version = version
-            r.future.client_id = r.client_id
-            r.future.set_result((y, p))
-        if fspans is not None:
-            # exported traces (the transport worker's done-callback runs
-            # inside set_result) materialized before this stamp and are
-            # closed, so the reply span and finish only land on the
-            # in-process traces — see obs.trace
-            fspans.stamp("reply")
-            _finish_all(traced)
-            if deferred:
-                tracer.finish_block("step", self._meta_for(model_key),
-                                    fspans, deferred)
+        with obs_trace.span("repro.engine.reply"):
+            if fspans is not None:
+                fspans.stamp("dispatch")
+            now = time.perf_counter()
+            version = getattr(fc, "version", None)
+            # lane slots actually dispatched (waves for duplicate clients,
+            # each padded to the decode width) — counted by the runner at
+            # the dispatch decision, not re-derived here
+            padded = getattr(runner, "last_step_slots", len(reqs))
+            self.telemetry.record_step_batch([now - r.t_enq for r in reqs],
+                                             n_padded=padded,
+                                             model=model_key)
+            spec = self._ensemble_spec(model_key)
+            if spec is not None:
+                # the fuse happened inside the ensemble runtime's step_many;
+                # surface its alert/anomaly outcome into telemetry and the
+                # flush worker's max_wait multipliers
+                rt = self._ensemble(model_key)
+                thr = rt.fuser().alert_threshold()
+                self._note_anomaly(model_key, spec, rt)
+                self.telemetry.record_ensemble(
+                    alerts=sum(1 for _, p in outs if p >= thr), n=len(outs),
+                    anomaly=rt.fuser().anomaly)
+            if fspans is not None:
+                # scatter + the umbrella flush span BEFORE set_result: the
+                # transport worker's done-callback exports the trace, so
+                # anything after delivery would be lost cross-process
+                fspans.umbrella("flush", t0f, fspans.stamp("scatter"))
+            for r, (y, p) in zip(reqs, outs):
+                r.future.model_version = version
+                r.future.client_id = r.client_id
+                r.future.set_result((y, p))
+            if fspans is not None:
+                # exported traces (the transport worker's done-callback runs
+                # inside set_result) materialized before this stamp and are
+                # closed, so the reply span and finish only land on the
+                # in-process traces — see obs.trace
+                fspans.stamp("reply")
+                _finish_all(traced)
+                if deferred:
+                    tracer.finish_block("step", self._meta_for(model_key),
+                                        fspans, deferred)
 
-    def _flush(self, model_key: str, bucket_t: int,
-               reqs: list[_Request]) -> None:
-        if bucket_t == _STEP_BUCKET:
-            self._flush_steps(model_key, reqs)
-            return
+    def _flush(self, model_key: str, bucket_t: int, reqs: list) -> None:
         # transition futures to RUNNING; drops client-cancelled requests
         # and guarantees the set_result/set_exception below cannot raise
         # InvalidStateError into the worker thread
         reqs = [r for r in reqs if r.future.set_running_or_notify_cancel()]
         if not reqs:
             return
+        if bucket_t == _STEP_BUCKET:
+            with obs_trace.span("repro.engine.flush.step"):
+                self._flush_steps(model_key, reqs)
+        else:
+            with obs_trace.span("repro.engine.flush.predict"):
+                self._flush_predict(model_key, bucket_t, reqs)
+
+    def _flush_predict(self, model_key: str, bucket_t: int,
+                       reqs: list[_Request]) -> None:
         tracer = self.tracer
         traced, deferred, fspans = self._trace_gather(tracer, reqs)
         if fspans is not None:
             t0f = fspans.stamp("queue")   # enqueue -> flush start
         try:
-            # one atomic reference per flush: the whole micro-batch serves
-            # on these weights even if the registry swaps mid-predict; the
-            # next flush re-resolves and picks up the new version
-            fc = self.registry.get(model_key)
-            bucket_b = self.config.bucket_batch(len(reqs))
-            x, lens = self._padded(fc, [r.payload for r in reqs],
-                                   [r.length for r in reqs], bucket_b,
-                                   bucket_t)
+            with obs_trace.span("repro.engine.gather"):
+                # one atomic reference per flush: the whole micro-batch
+                # serves on these weights even if the registry swaps
+                # mid-predict; the next flush re-resolves and picks up
+                # the new version
+                fc = self.registry.get(model_key)
+                bucket_b = self.config.bucket_batch(len(reqs))
+                x, lens = self._padded(fc, [r.payload for r in reqs],
+                                       [r.length for r in reqs], bucket_b,
+                                       bucket_t)
             if fspans is not None:
                 fspans.stamp("gather", meta={"batch": len(reqs),
                                              "padded": bucket_b})
@@ -791,41 +797,42 @@ class EngineShard:
                 tracer.finish_block("predict", self._meta_for(model_key),
                                     fspans, deferred, status="error")
             return
-        if fspans is not None:
-            fspans.stamp("dispatch")
-        now = time.perf_counter()
-        version = getattr(fc, "version", None)
-        published = getattr(fc, "published_at", None)
-        staleness = (now - published) if published is not None else None
-        self.telemetry.record_batch(len(reqs), bucket_b)
-        self.telemetry.record_requests([now - r.t_enq for r in reqs],
-                                       version=version,
-                                       staleness_s=staleness,
-                                       client_ids=[r.client_id
-                                                   for r in reqs],
-                                       model=model_key)
-        if fspans is not None:
-            # scatter + the umbrella flush span (overlapping the chained
-            # queue/gather/dispatch/scatter spans) BEFORE set_result:
-            # the transport worker's done-callback exports the trace, so
-            # anything recorded after delivery would be lost cross-process
-            fspans.umbrella("flush", t0f, fspans.stamp("scatter"))
-        for i, r in enumerate(reqs):
-            # attribution before set_result: a client that wakes on the
-            # result always sees which model version produced it
-            r.future.model_version = version
-            r.future.client_id = r.client_id
-            r.future.set_result((float(forecast[i]), float(p_extreme[i])))
-        if fspans is not None:
-            # exported traces (the transport worker's done-callback runs
-            # inside set_result) materialized before this stamp and are
-            # closed, so the reply span and finish only land on the
-            # in-process traces — see obs.trace
-            fspans.stamp("reply")
-            _finish_all(traced)
-            if deferred:
-                tracer.finish_block("predict", self._meta_for(model_key),
-                                    fspans, deferred)
+        with obs_trace.span("repro.engine.reply"):
+            if fspans is not None:
+                fspans.stamp("dispatch")
+            now = time.perf_counter()
+            version = getattr(fc, "version", None)
+            published = getattr(fc, "published_at", None)
+            staleness = (now - published) if published is not None else None
+            self.telemetry.record_batch(len(reqs), bucket_b)
+            self.telemetry.record_requests([now - r.t_enq for r in reqs],
+                                           version=version,
+                                           staleness_s=staleness,
+                                           client_ids=[r.client_id
+                                                       for r in reqs],
+                                           model=model_key)
+            if fspans is not None:
+                # scatter + the umbrella flush span (overlapping the chained
+                # queue/gather/dispatch/scatter spans) BEFORE set_result:
+                # the transport worker's done-callback exports the trace, so
+                # anything recorded after delivery would be lost cross-process
+                fspans.umbrella("flush", t0f, fspans.stamp("scatter"))
+            for i, r in enumerate(reqs):
+                # attribution before set_result: a client that wakes on the
+                # result always sees which model version produced it
+                r.future.model_version = version
+                r.future.client_id = r.client_id
+                r.future.set_result((float(forecast[i]), float(p_extreme[i])))
+            if fspans is not None:
+                # exported traces (the transport worker's done-callback runs
+                # inside set_result) materialized before this stamp and are
+                # closed, so the reply span and finish only land on the
+                # in-process traces — see obs.trace
+                fspans.stamp("reply")
+                _finish_all(traced)
+                if deferred:
+                    tracer.finish_block("predict", self._meta_for(model_key),
+                                        fspans, deferred)
 
     def _flush_all(self) -> None:
         """Dispatch every pending group right now (max_batch chunks)."""
@@ -881,7 +888,9 @@ class EngineShard:
                           for k, r in self._pending.items())
                 - time.perf_counter())
             try:
-                model_key, req = self._queue.get(timeout=min(timeout, 0.05))
+                with obs_trace.span("repro.engine.wait"):
+                    model_key, req = self._queue.get(
+                        timeout=min(timeout, 0.05))
             except queue.Empty:
                 continue
             if isinstance(req, _Quiesce):

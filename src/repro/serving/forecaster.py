@@ -44,6 +44,7 @@ from repro.kernels import dispatch
 from repro.models.rnn import (RNNConfig, init_rnn, init_rnn_carry,
                               rnn_apply_padded, rnn_step, split_rnn_carry,
                               stack_rnn_carries)
+from repro.obs import trace as obs_trace
 
 PyTree = Any
 
@@ -420,11 +421,12 @@ class LSTMForecaster:
         lengths = jnp.asarray(lengths, jnp.int32)
         dispatch.record("predict", batch=int(windows.shape[0]),
                         hidden=self.cfg.hidden, kernel_op="lstm_cell")
-        with jax.profiler.TraceAnnotation("repro.predict"):
+        with obs_trace.span("repro.predict"):
             y, p = self._fns["predict"](self.params, windows, lengths,
                                         *self._tail_args(),
                                         gamma=float(self.gamma))
-        return np.asarray(y), np.asarray(p)
+        with obs_trace.span("repro.readback"):
+            return np.asarray(y), np.asarray(p)
 
     def _tail_args(self):
         """(xi, scale, active) for the fused alert: dummies + inactive
@@ -476,7 +478,7 @@ class LSTMForecaster:
             return np.concatenate(ys), np.concatenate(ps), stacked
         dispatch.record("decode_step", batch=W, hidden=self.cfg.hidden,
                         kernel_op="lstm_cell")
-        with jax.profiler.TraceAnnotation("repro.decode_step"):
+        with obs_trace.span("repro.decode_step"):
             y, p, carry = self._fns["decode_step"](self.params, x_t, carry,
                                                    *self._tail_args(),
                                                    gamma=float(self.gamma),
@@ -521,7 +523,7 @@ class LSTMForecaster:
             x[:n] = xs[lo:lo + n]
             dispatch.record("decode_many", batch=W, hidden=self.cfg.hidden,
                             kernel_op="lstm_cell")
-            with jax.profiler.TraceAnnotation("repro.decode_many"):
+            with obs_trace.span("repro.decode_many"):
                 y, p, sessions = fn(self.params, x, tuple(chunk),
                                     *self._tail_args(),
                                     gamma=float(self.gamma))
@@ -557,7 +559,7 @@ class LSTMForecaster:
             return np.concatenate(ys), np.concatenate(ps), stacked
         dispatch.record("decode_replay", batch=W, hidden=self.cfg.hidden,
                         kernel_op="lstm_cell")
-        with jax.profiler.TraceAnnotation("repro.decode_replay"):
+        with obs_trace.span("repro.decode_replay"):
             ys, ps, _, carry = self._fns["decode_replay"](
                 self.params, window, carry, *self._tail_args(),
                 gamma=float(self.gamma), width=W)
@@ -596,7 +598,7 @@ class LSTMForecaster:
         dispatch.record("slots_insert", batch=1, hidden=self.cfg.hidden,
                         kernel_op="lstm_cell")
         fn = self._fns["slots_insert_donate" if donate else "slots_insert"]
-        with jax.profiler.TraceAnnotation("repro.slots_insert"):
+        with obs_trace.span("repro.slots_insert"):
             slots.carry = fn(slots.carry, carry, jnp.int32(lane))
         slots.active[lane] = True
         return slots
@@ -608,7 +610,7 @@ class LSTMForecaster:
         what ``insert`` + ``generate`` steps produced."""
         dispatch.record("slots_extract", batch=1, hidden=self.cfg.hidden,
                         kernel_op="lstm_cell")
-        with jax.profiler.TraceAnnotation("repro.slots_extract"):
+        with obs_trace.span("repro.slots_extract"):
             return self._fns["slots_extract"](slots.carry, jnp.int32(lane))
 
     def release(self, slots: DecodeSlots, lane: int) -> None:
@@ -643,12 +645,13 @@ class LSTMForecaster:
                         kernel_op="lstm_cell")
         fn = self._fns["slots_generate_donate" if donate
                        else "slots_generate"]
-        with jax.profiler.TraceAnnotation("repro.slots_generate"):
+        with obs_trace.span("repro.slots_generate"):
             y, p, carry = fn(self.params, x, slots.carry, mask,
                              *self._tail_args(), gamma=float(self.gamma),
                              width=self.decode_width)
         slots.carry = carry
-        return np.asarray(y), np.asarray(p), slots
+        with obs_trace.span("repro.readback"):
+            return np.asarray(y), np.asarray(p), slots
 
     def warm_slots(self, num_slots: int) -> int:
         """Compile the slot lifecycle programs (insert/extract/generate,

@@ -50,8 +50,7 @@ import warnings
 from collections import OrderedDict
 from typing import Any
 
-import jax
-
+from repro.obs import trace as obs_trace
 from repro.serving.router import ConsistentRouter
 from repro.serving.telemetry import Telemetry
 
@@ -714,7 +713,7 @@ class RecurrentSessionRunner:
             # clients than there are lanes)
             S = self.num_slots
             n_chunks = 0
-            with jax.profiler.TraceAnnotation("repro.session_step_many"):
+            with obs_trace.span("repro.session_step_many"):
                 with self._slots_lock:
                     self._expire_lanes_locked(fc)
                     for wave in waves:
@@ -736,7 +735,7 @@ class RecurrentSessionRunner:
         self.last_step_slots = sum(
             (-(-len(w) // width) * width) if width else len(w)
             for w in waves)
-        with jax.profiler.TraceAnnotation("repro.session_step_many"):
+        with obs_trace.span("repro.session_step_many"):
             self._run_waves(fc, items, waves, version, results)
         return results
 
@@ -806,16 +805,17 @@ class RecurrentSessionRunner:
             return lane
         # lane miss: spill tier -> history prefill -> zeros/error, with
         # the same version semantics as the cache path
-        carry, stamp = self._resolve_carry(fc, cid, hist, version)
-        self.cache.drop(cid)          # the lane owns the state now
-        lane = self._alloc_lane_locked(fc)
-        self._guarded_insert(fc, lane, carry)
-        self._lanes[cid] = lane
-        self._lane_stamp[cid] = stamp
-        self._lane_last_used[cid] = now
-        self.slot_inserts += 1
-        if self.cache.telemetry is not None:
-            self.cache.telemetry.record_slots(inserts=1)
+        with obs_trace.span("repro.sessions.lane_miss"):
+            carry, stamp = self._resolve_carry(fc, cid, hist, version)
+            self.cache.drop(cid)          # the lane owns the state now
+            lane = self._alloc_lane_locked(fc)
+            self._guarded_insert(fc, lane, carry)
+            self._lanes[cid] = lane
+            self._lane_stamp[cid] = stamp
+            self._lane_last_used[cid] = now
+            self.slot_inserts += 1
+            if self.cache.telemetry is not None:
+                self.cache.telemetry.record_slots(inserts=1)
         return lane
 
     def _guarded_insert(self, fc, lane, carry) -> None:

@@ -47,9 +47,6 @@ class _Reservoir:
             self._buf[self._pos] = value
             self._pos = (self._pos + 1) % self.capacity
 
-    def percentile(self, p: float) -> float:
-        return _percentile(self._buf, p)
-
     def percentiles(self, ps) -> list[float]:
         return _percentiles(self._buf, ps)
 
@@ -251,10 +248,6 @@ class Telemetry:
                 self.slot_lanes = lanes
 
     # -- reading -----------------------------------------------------------
-    def latency_percentile_ms(self, p: float) -> float:
-        with self._lock:
-            return self._latency.percentile(p) * 1e3
-
     def _raw_samples_locked(self) -> dict:
         return {
             "latency_s": list(self._latency._buf),
