@@ -142,6 +142,31 @@ def sync_step(loss_fn: Callable, optimizer: Optimizer,
     return broadcast_to_workers(avg, p), o, losses[:, 0]
 
 
+def round_program(loss_fn: Callable, optimizer: Optimizer, tau: int,
+                  stacked_params: PyTree, stacked_opt: PyTree,
+                  batches: PyTree, lr):
+    """The whole model-exchange round, for one jit: the local steps, the
+    workers' mean model and the round's mean loss. At ``tau`` 0 the mean
+    is broadcast back, so it returns (params, opt, mean loss); above, it
+    returns (params, opt, mean loss, this round's mean) for the stale
+    apply ``tau`` rounds later. ``worker_mean`` and ``broadcast_to_workers``
+    are read as module globals when the program is traced."""
+    p, o, losses = local_sgd_round(loss_fn, optimizer, stacked_params,
+                                   stacked_opt, batches, lr)
+    avg = worker_mean(p)
+    if tau == 0:
+        return broadcast_to_workers(avg, p), o, jnp.mean(losses)
+    return p, o, jnp.mean(losses), avg
+
+
+@jax.jit
+def _stale_apply(avg: PyTree, p: PyTree, snapshot: PyTree) -> PyTree:
+    """w <- avg^{(r)} + (w - w^{(r)}): a round's average applied ``tau``
+    rounds late, each worker keeping its delta since the snapshot."""
+    return jax.tree.map(lambda a, w, s: (a[None] + (w - s)).astype(w.dtype),
+                        avg, p, snapshot)
+
+
 # --------------------------------------------------------------------------
 # High-level trainer
 # --------------------------------------------------------------------------
@@ -156,18 +181,26 @@ class AsyncLocalSGD:
         self.loss_fn = loss_fn
         self.optimizer = optimizer
         self.cfg = config
+        # built per trainer: a trainer traces the module's worker_mean
+        # as it stands when its first round runs
         self._round = jax.jit(
-            lambda p, o, b, lr: local_sgd_round(
-                loss_fn, optimizer, p, o, b, lr))
-        self._sync = jax.jit(
-            lambda p, o, b, lr: sync_step(
-                loss_fn, optimizer, p, o, b, lr, exchange="gradient"))
+            lambda p, o, b, lr: round_program(
+                loss_fn, optimizer, config.tau, p, o, b, lr))
+
+        def sync(p, o, b, lr):
+            p, o, losses = sync_step(loss_fn, optimizer, p, o, b, lr,
+                                     exchange="gradient")
+            return p, o, jnp.mean(losses)
+
+        self._sync = jax.jit(sync)
         # (avg, snapshot, round index the average was computed at)
         self._avg_queue: list[tuple[PyTree, PyTree, int]] = []
         # accounting
         self.rounds_done = 0
         self.iterations_done = 0
         self.communications = 0
+        # rounds whose model exchange ran inside the round program
+        self.in_program_exchanges = 0
         self.loss_history: list[float] = []
         # Definition 1 audit trail: (round applied at, round averaged at),
         # i.e. each entry asserts "round r consumed the round r - tau avg"
@@ -194,10 +227,12 @@ class AsyncLocalSGD:
                   batches: PyTree) -> tuple[PyTree, PyTree, float]:
         """batches leaves: [W, H, ...] with H = local_steps_for_round(r+1).
 
-        The round's phases are host spans on the profiler's clock
-        (``repro.obs.trace.SPANS``): the round program's call, the model
-        exchange, the loss read. The call copies numpy batches to the
-        device itself: an explicit ``jax.device_put`` in front of it
+        A round is one program call and one scalar read back: the local
+        steps, the model exchange at ``tau`` 0 and the mean loss all run
+        in the round program. Only the stale apply (``tau`` >= 1) is a
+        second call. The phases are host spans on the profiler's clock
+        (``repro.obs.trace.SPANS``). The call copies numpy batches to
+        the device itself: an explicit ``jax.device_put`` in front of it
         cost about 4% of the paper job's samples per second on a v5e."""
         with obs_trace.span("repro.round"):
             lr = self.lr_for_round()
@@ -211,33 +246,28 @@ class AsyncLocalSGD:
                         f"steps")
                 batches = jax.tree.map(lambda b: b[:, 0], batches)
             with obs_trace.span("repro.round.local"):
-                p, o, losses = (self._sync if gradient else self._round)(
+                p, o, loss, *avg = (self._sync if gradient else self._round)(
                     stacked_params, stacked_opt, batches, lr)
             self.iterations_done += H * self.cfg.n_workers
             self.rounds_done += 1
             self.communications += 1
-            if not gradient:
+            if avg:
                 with obs_trace.span("repro.round.exchange"):
-                    p = self._exchange(p)
+                    p = self._stale_exchange(p, avg[0])
+            elif not gradient:
+                self.in_program_exchanges += 1
             with obs_trace.span("repro.round.loss"):
-                mean_loss = float(jnp.mean(losses))
+                mean_loss = float(loss)
             self.loss_history.append(mean_loss)
             return p, o, mean_loss
 
-    def _exchange(self, p: PyTree) -> PyTree:
-        """The model exchange after a round's local steps: average now
-        (``tau`` 0), or queue this round's average and apply the one
-        from ``tau`` rounds ago."""
-        if self.cfg.tau == 0:
-            return broadcast_to_workers(worker_mean(p), p)
-        avg_now = worker_mean(p)
-        snapshot = p
-        self._avg_queue.append((avg_now, snapshot, self.rounds_done))
+    def _stale_exchange(self, p: PyTree, avg_now: PyTree) -> PyTree:
+        """Queue this round's average and apply the one from ``tau``
+        rounds ago."""
+        self._avg_queue.append((avg_now, p, self.rounds_done))
         if len(self._avg_queue) > self.cfg.tau:
             avg_old, snap_old, round_old = self._avg_queue.pop(0)
-            p = jax.tree.map(
-                lambda a, w, s: (a[None] + (w - s)).astype(w.dtype),
-                avg_old, p, snap_old)
+            p = _stale_apply(avg_old, p, snap_old)
             self.consumed_rounds.append((self.rounds_done, round_old))
         return p
 

@@ -117,11 +117,11 @@ SPANS = {
         "extract and spill, the insert",
     "repro.round": "one local-SGD round of the trainer",
     "repro.round.local":
-        "the call of the round's local-steps program, with the copy of "
-        "its batches to the device",
+        "the call of the round program, with the copy of its batches to "
+        "the device; at tau 0 the program holds the model exchange",
     "repro.round.exchange":
-        "the model exchange: worker mean and broadcast, or the stale "
-        "average applied",
+        "the stale apply at tau >= 1: a round's average applied tau "
+        "rounds late",
     "repro.round.loss": "the host waiting for the round's mean loss",
 }
 

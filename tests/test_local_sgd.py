@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.core.async_local_sgd as asgd
 from repro.core.async_local_sgd import (AsyncLocalSGD, LocalSGDConfig,
                                         broadcast_to_workers,
                                         local_sgd_round, sync_step,
@@ -223,3 +224,73 @@ def test_stale_averaging_satisfies_definition_1():
         assert len(trainer._avg_queue) <= cfg.tau
     # still converges despite staleness
     assert trainer.loss_history[-1] < trainer.loss_history[0]
+
+
+def _eager_round(p, o, batches, lr, queue, tau, r):
+    """One round as the trainer composed it from eager pieces: the local
+    steps' program, the exchange on the host (sync, or stale over
+    ``queue``), then the mean loss."""
+    p, o, losses = local_sgd_round(quad_loss, sgd(), p, o, batches, lr)
+    if tau == 0:
+        p = broadcast_to_workers(worker_mean(p), p)
+    else:
+        queue.append((worker_mean(p), p, r))
+        if len(queue) > tau:
+            avg_old, snap_old, _ = queue.pop(0)
+            p = jax.tree.map(lambda a, w, s: a[None] + (w - s),
+                             avg_old, p, snap_old)
+    return p, o, float(jnp.mean(losses))
+
+
+@pytest.mark.parametrize("tau,H,rounds", [(0, 1, 1), (0, 3, 1), (0, 7, 1),
+                                          (1, 3, 4)])
+def test_round_program_matches_eager_exchange(monkeypatch, tau, H, rounds):
+    """The exchange and the mean loss inside the round program give the
+    models and losses of the eager composition; at tau 0 every round's
+    exchange ran in the program, and a round is one program call that
+    returns the mean loss as a scalar, with no exchange on the host."""
+    W = 3
+    x, y = _data(W * H * 8 * rounds, seed=H)
+    cfg = LocalSGDConfig(n_workers=W, tau=tau,
+                         stepsize=StepSizeSchedule(eta0=0.05, beta=0.0))
+    trainer = AsyncLocalSGD(quad_loss, sgd(), cfg)
+    rng = np.random.default_rng(H)
+    p0 = {"w": jnp.asarray(rng.standard_normal(3), jnp.float32),
+          "b": jnp.zeros(())}
+    stacked, opt = trainer.init(p0)
+    want_p, want_o = stacked, opt
+    queue = []
+    for r in range(rounds):
+        idx = rng.permutation(len(x)).reshape(W, H, -1)[..., :8]
+        batches = (x[idx], y[idx])
+        lr = trainer.lr_for_round()
+        want_p, want_o, want_loss = _eager_round(
+            want_p, want_o, batches, lr, queue, tau, r + 1)
+        stacked, opt, loss = trainer.run_round(stacked, opt, batches)
+        np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+        for k in ("w", "b"):
+            np.testing.assert_allclose(np.asarray(stacked[k]),
+                                       np.asarray(want_p[k]), rtol=1e-6)
+    assert trainer.loss_history[-1] == loss
+    assert trainer.in_program_exchanges == (
+        trainer.communications if tau == 0 else 0)
+    if tau:
+        assert trainer.consumed_rounds == [(r, r - tau)
+                                           for r in range(2, rounds + 1)]
+    else:
+        calls = []
+        program = trainer._round
+
+        def counted(*args):
+            calls.append(program(*args))
+            return calls[-1]
+
+        def on_host(*args):
+            raise AssertionError("model exchange ran on the host")
+
+        monkeypatch.setattr(trainer, "_round", counted)
+        monkeypatch.setattr(asgd, "worker_mean", on_host)
+        monkeypatch.setattr(asgd, "broadcast_to_workers", on_host)
+        trainer.run_round(stacked, opt, batches)
+        assert len(calls) == 1 and calls[0][2].shape == ()
+        assert trainer.in_program_exchanges == trainer.communications

@@ -167,8 +167,7 @@ def quad_loss(params, batch):
 
 
 @pytest.mark.parametrize("tau,exchange,phases", [
-    (0, "model", ["repro.round.local", "repro.round.exchange",
-                  "repro.round.loss"]),
+    (0, "model", ["repro.round.local", "repro.round.loss"]),
     (1, "model", ["repro.round.local", "repro.round.exchange",
                   "repro.round.loss"]),
     (0, "gradient", ["repro.round.local", "repro.round.loss"]),
