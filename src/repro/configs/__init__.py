@@ -11,6 +11,7 @@ from repro.configs.shapes import INPUT_SHAPES, InputShape
 from repro.configs import (  # noqa: F401
     chameleon_34b,
     granite_20b,
+    granite_4_0_h_micro,
     mamba2_370m,
     mixtral_8x7b,
     nemotron_4_15b,

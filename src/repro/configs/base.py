@@ -46,9 +46,27 @@ class ArchConfig:
     ssm_head_dim: int = 64
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    # dt = softplus(.) is clipped to this range (Mamba2's time_step_limit)
+    ssm_dt_limit: tuple[float, float] = (1e-4, 1e2)
     # hybrid (zamba2): one shared attention+MLP block applied every
     # ``attn_every`` SSM layers (tied weights)
     attn_every: int = 0
+    # hybrid by layer pattern (granite-4.0-h): the mixer of each layer,
+    # "mamba" or "attention", each with weights of its own and followed
+    # by the gated MLP. Empty for every other family.
+    layer_types: tuple[str, ...] = ()
+    # Granite's scalings: the input embeddings are multiplied by
+    # ``embedding_multiplier``, each residual branch by
+    # ``residual_multiplier``, the logits divided by ``logits_scaling``;
+    # ``attention_multiplier`` (when set) replaces 1/sqrt(head_dim).
+    # Read by the layer-pattern path only.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    positions: str = "rope"         # rope | nope (no positional encoding)
+    tie_embeddings: bool = False    # the output head is the embedding
+    norm_eps: float = 1e-6
     # enc-dec (whisper)
     encoder_layers: int = 0
     n_frames: int = 1500            # encoder source positions (stub frontend)
@@ -104,6 +122,8 @@ class ArchConfig:
         d, f, L = self.d_model, self.d_ff, self.n_layers
         emb = self.padded_vocab * d * 2  # embed + lm_head (untied)
         per_layer = 0
+        if self.layer_types:
+            return self._pattern_params()
         if self.family == "ssm":
             per_layer = self._ssm_block_params()
         elif self.family == "hybrid":
@@ -140,6 +160,20 @@ class ArchConfig:
     def _attn_params(self) -> int:
         d = self.d_model
         return d * self.q_dim * 2 + d * self.kv_dim * 2
+
+    def _pattern_params(self) -> int:
+        """Parameters of the layer-pattern hybrid: per layer its mixer
+        (with its norm) and the gated MLP (with its norm)."""
+        d, di, N, H = self.d_model, self.d_inner, self.ssm_state, self.ssm_heads
+        conv_dim = di + 2 * N
+        mamba = (d * (2 * di + 2 * N + H) + conv_dim * (self.ssm_conv + 1)
+                 + 3 * H + di + di * d + d)
+        attn = self._attn_params() + d
+        mlp = (3 if self.gated_mlp else 2) * d * self.d_ff + d
+        emb = self.padded_vocab * d * (1 if self.tie_embeddings else 2)
+        n_attn = self.layer_types.count("attention")
+        return (emb + d + (len(self.layer_types) - n_attn) * mamba
+                + n_attn * attn + len(self.layer_types) * mlp)
 
     def _ssm_block_params(self) -> int:
         d = self.d_model
@@ -194,6 +228,9 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
     if cfg.attn_every:
         small["attn_every"] = 1
         small["n_kv_heads"] = small["n_heads"]
+    if cfg.layer_types:
+        # one layer of each kind, in the published order of first use
+        small["layer_types"] = tuple(dict.fromkeys(cfg.layer_types))
     if cfg.encoder_layers:
         small["encoder_layers"] = 2
         small["n_frames"] = 16
@@ -205,3 +242,48 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
     small["dtype"] = "float32"
     small.update(overrides)
     return dataclasses.replace(cfg, **small)
+
+
+# a layer-pattern hybrid's published config.json key -> ArchConfig field
+_HYBRID_KEYS = {
+    "hidden_size": "d_model", "num_hidden_layers": "n_layers",
+    "layer_types": "layer_types", "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads", "shared_intermediate_size": "d_ff",
+    "vocab_size": "vocab", "mamba_d_head": "ssm_head_dim",
+    "mamba_d_state": "ssm_state", "mamba_d_conv": "ssm_conv",
+    "mamba_expand": "ssm_expand", "mamba_chunk_size": "ssm_chunk",
+    "attention_multiplier": "attention_multiplier",
+    "embedding_multiplier": "embedding_multiplier",
+    "residual_multiplier": "residual_multiplier",
+    "logits_scaling": "logits_scaling", "rms_norm_eps": "norm_eps",
+    "position_embedding_type": "positions",
+    "tie_word_embeddings": "tie_embeddings",
+    "time_step_limit": "ssm_dt_limit",
+}
+
+
+def hybrid_config(cfg: ArchConfig) -> dict:
+    """A layer-pattern hybrid's sizes under the names of its published
+    ``config.json`` (granitemoehybrid), with the dt clip as
+    ``time_step_limit``: what ``repro.models.hybrid_ref`` reads."""
+    if not cfg.layer_types:
+        raise ValueError(f"{cfg.name} is not a hybrid by layer pattern")
+    out = {k: getattr(cfg, f) for k, f in _HYBRID_KEYS.items()}
+    out.update(layer_types=list(cfg.layer_types),
+               time_step_limit=list(cfg.ssm_dt_limit),
+               mamba_n_heads=cfg.ssm_heads, mamba_n_groups=1)
+    return out
+
+
+def from_hybrid_config(c: dict, base: ArchConfig) -> ArchConfig:
+    """``base`` with the sizes of a published layer-pattern hybrid config
+    ``c`` (the names ``hybrid_config`` gives)."""
+    if c["mamba_n_groups"] != 1 or c["mamba_n_heads"] * c["mamba_d_head"] \
+            != c["mamba_expand"] * c["hidden_size"]:
+        raise ValueError("the Mamba2 here has one group, and heads that "
+                         "tile hidden_size x mamba_expand")
+    fields = {f: c[k] for k, f in _HYBRID_KEYS.items()}
+    fields.update(layer_types=tuple(c["layer_types"]),
+                  ssm_dt_limit=tuple(float(x) for x in c["time_step_limit"]),
+                  head_dim=c["hidden_size"] // c["num_attention_heads"])
+    return dataclasses.replace(base, **fields)

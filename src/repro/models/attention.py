@@ -31,7 +31,7 @@ def _pad_to(x, axis: int, multiple: int):
 def blocked_attention(q, k, v, *, causal: bool = True,
                       window: int | None = None,
                       q_block: int = 512, kv_block: int = 1024,
-                      q_offset: int = 0):
+                      q_offset: int = 0, scale: float | None = None):
     """Online-softmax attention.
 
     Args:
@@ -40,6 +40,7 @@ def blocked_attention(q, k, v, *, causal: bool = True,
         causal: apply causal mask (query position = q_offset + index).
         window: sliding-window size (keys within [pos-window+1, pos]).
         q_offset: absolute position of q[0] (for decode/chunked prefill).
+        scale: score scale (default 1/sqrt(D)).
 
     Returns [B, Sq, Hq, D] in q.dtype.
     """
@@ -47,7 +48,7 @@ def blocked_attention(q, k, v, *, causal: bool = True,
     B, Sq, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     q, Sq0 = _pad_to(q, 1, q_block)
     k, Skv0 = _pad_to(k, 1, kv_block)

@@ -184,7 +184,8 @@ def mamba2_apply(p, x, *, head_dim: int, ssm_state: int, chunk: int = 128,
 
 
 def mamba2_decode(p, x_t, conv_state, ssm_state_arr, *, head_dim: int,
-                  ssm_state: int, dt_limit=(1e-4, 1e2)):
+                  ssm_state: int, dt_limit=(1e-4, 1e2),
+                  norm_eps: float = 1e-6):
     """One-token decode. x_t [B, D]. Returns (y [B, D], conv_state, state)."""
     Bsz, D = x_t.shape
     d_inner = p["out_proj"].shape[0]
@@ -209,5 +210,5 @@ def mamba2_decode(p, x_t, conv_state, ssm_state_arr, *, head_dim: int,
     y, ssm_state_arr = ssd_decode_step(ssm_state_arr, xd_t, a_t, B_, C_)
     y = y + xs * p["D"][None, :, None]
     y = y.reshape(Bsz, d_inner)
-    y = rms_norm(y * silu(z), p["norm_w"])
+    y = rms_norm(y * silu(z), p["norm_w"], norm_eps)
     return y @ p["out_proj"], conv_state, ssm_state_arr

@@ -1,5 +1,6 @@
-"""Transformer zoo: decoder LMs (dense / MoE / VLM), SSM stacks, hybrid
-(Mamba2 + shared attention), and encoder-decoder (Whisper backbone).
+"""Transformer zoo: decoder LMs (dense / MoE / VLM), SSM stacks, hybrids
+(Mamba2 + shared attention; Mamba2 and attention by layer pattern), and
+encoder-decoder (Whisper backbone).
 
 Functional design:
     params = init_lm(cfg, key)                  (or jax.eval_shape for dry-run)
@@ -134,6 +135,8 @@ def _init_decoder_layer(key, cfg: ArchConfig, dt, cross: bool = False):
 
 def init_lm(cfg: ArchConfig, key) -> PyTree:
     """Build the parameter pytree for any assigned architecture."""
+    if cfg.layer_types:
+        return _init_pattern(cfg, key)
     dt = _dtype(cfg)
     ks = jax.random.split(key, 8)
     V, d = cfg.padded_vocab, cfg.d_model
@@ -312,6 +315,8 @@ def lm_forward(cfg: ArchConfig, params: PyTree, tokens, frames=None):
     tokens: int32 [B, S]. frames: [B, n_frames, d] for audio archs.
     Returns (logits [B, S, padded_vocab], aux_loss scalar).
     """
+    if cfg.layer_types:
+        return _pattern_forward(cfg, params, tokens), jnp.zeros((), jnp.float32)
     B, S = tokens.shape
     x = _embed(cfg, params, tokens)
     positions = jnp.arange(S)[None, :].repeat(B, 0)
@@ -392,6 +397,8 @@ def _attn_cache_mode(cfg: ArchConfig, max_len: int) -> tuple[str, int]:
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> PyTree:
+    if cfg.layer_types:
+        return _pattern_cache(cfg, batch, max_len)
     dt = _dtype(cfg)
     hd, Hkv = cfg.head_dim, cfg.n_kv_heads
     cache: dict = {"len": jnp.zeros((), jnp.int32)}
@@ -473,12 +480,22 @@ def _decode_ssm_block(cfg: ArchConfig, lp, x, conv_state, ssm_state):
     return x + y[:, None], conv_state, ssm_state
 
 
-def lm_decode_step(cfg: ArchConfig, params: PyTree, token, cache: PyTree):
+def lm_decode_step(cfg: ArchConfig, params: PyTree, token, cache: PyTree,
+                   active=None):
     """One decode step. token: int32 [B]. Returns (logits [B, V], cache).
 
     Attention caches: ring mode writes in place (slot = pos % W); full
     mode writes only the replicated recent buffer — the sharded main
-    cache passes through untouched (flushed by ``flush_recent``)."""
+    cache passes through untouched (flushed by ``flush_recent``).
+
+    The layer-pattern hybrid's cache keeps a length per row, and
+    ``active`` [B] bool (its only) steps some rows and leaves the others
+    as they were (see ``_pattern_decode``)."""
+    if cfg.layer_types:
+        return _pattern_decode(cfg, params, token, cache, active)
+    if active is not None:
+        raise ValueError(f"{cfg.name}: rows share one cache length; "
+                         f"every row steps")
     pos = cache["len"]
     full = "kr" in cache
     flushed = cache.get("flushed", jnp.zeros((), jnp.int32))
@@ -625,9 +642,15 @@ def _pack_prefill_attn(cfg: ArchConfig, k, v, S: int) -> dict:
             "flushed": jnp.asarray(S, jnp.int32)}
 
 
-def lm_prefill(cfg: ArchConfig, params: PyTree, tokens, frames=None):
+def lm_prefill(cfg: ArchConfig, params: PyTree, tokens, frames=None,
+               max_len: int | None = None):
     """Prefill: forward over the prompt, building the decode cache.
-    Returns (last-token logits [B, V], cache)."""
+    Returns (last-token logits [B, V], cache). The layer-pattern
+    hybrid's cache holds ``max_len`` positions (default: the prompt's)."""
+    if cfg.layer_types:
+        x, cache = pattern_prefill(cfg, params, tokens,
+                                   max_len or tokens.shape[1])
+        return pattern_logits(cfg, params, x[:, -1]), cache
     B, S = tokens.shape
     x = _embed(cfg, params, tokens)
     positions = jnp.arange(S)[None, :].repeat(B, 0)
@@ -719,19 +742,309 @@ def _ssm_prefill_block(cfg: ArchConfig, p, x):
     z = zxbcdt[..., :d_inner]
     xBC = zxbcdt[..., d_inner:2 * d_inner + 2 * N]
     dt = zxbcdt[..., 2 * d_inner + 2 * N:]
-    conv_state = xBC[:, -(cfg.ssm_conv - 1):, :]
+    # the last K-1 conv inputs, zeros before the sequence's start
+    conv_state = jnp.pad(xBC, ((0, 0), (cfg.ssm_conv - 1, 0), (0, 0))
+                         )[:, -(cfg.ssm_conv - 1):, :]
     xBC = ssm_mod.silu(ssm_mod.causal_conv1d(xBC, p["conv_w"], p["conv_b"]))
     xs = xBC[..., :d_inner].reshape(Bsz, L, H, cfg.ssm_head_dim)
     B_ = xBC[..., d_inner:d_inner + N]
     C_ = xBC[..., d_inner + N:]
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
-    dt = jnp.clip(dt, 1e-4, 1e2)
+    dt = jnp.clip(dt, *cfg.ssm_dt_limit)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     a = dt * A[None, None, :]
     xd = xs * dt[..., None].astype(xs.dtype)
     y, final_state = ssm_mod.ssd_chunked(xd, a, B_, C_, chunk=cfg.ssm_chunk)
     y = y + xs * p["D"][None, None, :, None]
     y = y.reshape(Bsz, L, d_inner)
-    y = rms_norm(y * ssm_mod.silu(z), p["norm_w"])
+    y = rms_norm(y * ssm_mod.silu(z), p["norm_w"], cfg.norm_eps)
     return y @ p["out_proj"], conv_state, final_state
+
+
+# ==========================================================================
+# Hybrid by layer pattern (cfg.layer_types): granite-4.0-h
+# ==========================================================================
+#
+# Each layer is ``x + r * mixer(norm(x))`` then ``x + r * mlp(norm(x))``,
+# the mixer a Mamba2 block or NoPE GQA attention as ``cfg.layer_types``
+# says, each layer with weights of its own. Weights are stacked per kind
+# (``params["mamba"]``, ``params["attention"]``, and ``params["mlp"]``
+# for every layer); a run of consecutive layers of one kind is one loop that
+# indexes the stacks in place, so no layer's weights are copied.
+#
+# The decode cache keeps a length per row (``len`` [B]), so rows at
+# different positions step together: the serving lanes' state. Beside
+# it, per Mamba2 layer the conv inputs and the SSM state (float32 as
+# allocated; a step stores the state back in the cache's own dtype),
+# and per attention layer the keys and values of ``max_len`` positions,
+# the heads of a position side by side ([L, B, max_len, Hkv * hd]): a
+# step writes each row's new position in place, and a minor dimension
+# of Hkv * hd (not the 64 of one head) is not padded on the chip.
+
+MAMBA, ATTENTION = "mamba", "attention"
+
+
+def _runs(cfg: ArchConfig) -> list[tuple[str, int, int, int]]:
+    """(kind, first layer, layers, index of the first among its kind)
+    for each run of consecutive layers of one kind."""
+    runs: list[tuple[str, int, int, int]] = []
+    seen = {MAMBA: 0, ATTENTION: 0}
+    for i, kind in enumerate(cfg.layer_types):
+        if kind not in seen:
+            raise ValueError(f"{cfg.name}: unknown layer type {kind!r}")
+        if runs and runs[-1][0] == kind:
+            k, lo, n, k0 = runs[-1]
+            runs[-1] = (k, lo, n + 1, k0)
+        else:
+            runs.append((kind, i, 1, seen[kind]))
+        seen[kind] += 1
+    return runs
+
+
+def _layer_counts(cfg: ArchConfig) -> dict[str, int]:
+    return {kind: cfg.layer_types.count(kind) for kind in (MAMBA, ATTENTION)}
+
+
+def _init_pattern(cfg: ArchConfig, key) -> PyTree:
+    dt = _dtype(cfg)
+    d = cfg.d_model
+    ks = jax.random.split(key, 5)
+    counts = _layer_counts(cfg)
+    params: dict = {"embed": embed_init(ks[0], (cfg.padded_vocab, d), dt),
+                    "final_norm": norm_param(cfg.norm, d, dt)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense_init(ks[1], (d, cfg.padded_vocab), dt)
+    if counts[MAMBA]:
+        params[MAMBA] = _stack(
+            lambda k: {"norm": norm_param(cfg.norm, d, dt),
+                       "ssm": _init_ssm_block(k, cfg, dt)},
+            ks[2], counts[MAMBA])
+    if counts[ATTENTION]:
+        params[ATTENTION] = _stack(
+            lambda k: {"norm": norm_param(cfg.norm, d, dt),
+                       "attn": _init_attn(k, cfg, dt)},
+            ks[3], counts[ATTENTION])
+    params["mlp"] = _stack(lambda k: {"norm": norm_param(cfg.norm, d, dt),
+                                      "mlp": _init_mlp(k, cfg, dt)},
+                           ks[4], cfg.n_layers)
+    return params
+
+
+def _index(tree, i):
+    """Layer ``i`` of a stacked subtree (a static or traced index)."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+        tree)
+
+
+def _pattern_qkv(cfg: ArchConfig, p, h, positions):
+    return _project_qkv(cfg, p, h,
+                        positions if cfg.positions == "rope" else None)
+
+
+def _over_layers(cfg: ArchConfig, params, x, state, mix):
+    """Every layer in order. ``mix(kind, k, lp, h, state) -> (y, state)``
+    is the mixer of the ``k``-th layer of its kind on the normed input
+    ``h``; the residual scalings and the MLP half are common."""
+    r = cfg.residual_multiplier
+    eps = cfg.norm_eps
+
+    def layer(kind, i, k, x, state):
+        lp = _index(params[kind], k)
+        h = rms_norm(x, lp["norm"]["w"], eps)
+        y, state = mix(kind, k, lp, h, state)
+        x = x + (r * y).astype(x.dtype)
+        fp = _index(params["mlp"], i)
+        h = rms_norm(x, fp["norm"]["w"], eps)
+        y = mlp_apply(fp["mlp"], h, cfg.activation, cfg.gated_mlp)
+        return x + (r * y).astype(x.dtype), state
+
+    for kind, lo, n, k0 in _runs(cfg):
+        if n == 1:
+            x, state = layer(kind, lo, k0, x, state)
+            continue
+
+        def body(j, carry, kind=kind, lo=lo, k0=k0):
+            return layer(kind, lo + j, k0 + j, *carry)
+
+        x, state = jax.lax.fori_loop(0, n, body, (x, state))
+    return x, state
+
+
+def _pattern_embed(cfg: ArchConfig, params, tokens):
+    x = params["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = (x * cfg.embedding_multiplier).astype(x.dtype)
+    return x
+
+
+def pattern_logits(cfg: ArchConfig, params, h):
+    """float32 logits of final hidden states ``h`` [..., d] (normed
+    here): the head, tied or not, over ``logits_scaling``."""
+    h = rms_norm(h, params["final_norm"]["w"], cfg.norm_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.matmul(h, head, preferred_element_type=jnp.float32)
+    return logits / cfg.logits_scaling
+
+
+def _pattern_forward(cfg: ArchConfig, params, tokens):
+    B, S = tokens.shape
+    positions = jnp.arange(S)[None, :].repeat(B, 0)
+
+    def mix(kind, k, lp, h, state):
+        if kind == MAMBA:
+            y, _, _ = _ssm_prefill_block(cfg, lp["ssm"], h)
+            return y, state
+        q, kk, v = _pattern_qkv(cfg, lp["attn"], h, positions)
+        out = blocked_attention(q, kk, v, causal=True,
+                                scale=cfg.attention_multiplier)
+        return out.reshape(B, S, -1) @ lp["attn"]["wo"], state
+
+    x, _ = _over_layers(cfg, params, _pattern_embed(cfg, params, tokens), {},
+                        mix)
+    return pattern_logits(cfg, params, x)
+
+
+def _pattern_cache(cfg: ArchConfig, batch: int, max_len: int) -> PyTree:
+    dt = _dtype(cfg)
+    counts = _layer_counts(cfg)
+    cache: dict = {"len": jnp.zeros((batch,), jnp.int32)}
+    cache.update(_ssm_cache(cfg, batch, counts[MAMBA], dt))
+    n, hkv, hd = counts[ATTENTION], cfg.n_kv_heads, cfg.head_dim
+    cache["k"] = jnp.zeros((n, batch, max_len, hkv * hd), dt)
+    cache["v"] = jnp.zeros((n, batch, max_len, hkv * hd), dt)
+    return cache
+
+
+def cache_lane_axis(name: str) -> int:
+    """The batch (lane) axis of the layer-pattern cache's entry ``name``:
+    ``len`` is [B], every other entry is stacked over layers first."""
+    return 0 if name == "len" else 1
+
+
+def pattern_prefill(cfg: ArchConfig, params, tokens, max_len: int):
+    """Prefill of the layer-pattern hybrid: the final hidden states of
+    every position [B, S, d] (before the final norm; ``pattern_logits``
+    makes logits of them) and the decode cache, ``max_len`` positions per
+    row."""
+    B, S = tokens.shape
+    if S > max_len:
+        raise ValueError(f"a prompt of {S} tokens does not fit a cache of "
+                         f"{max_len} positions")
+    positions = jnp.arange(S)[None, :].repeat(B, 0)
+    cache = _pattern_cache(cfg, B, max_len)
+    cache["len"] = jnp.full((B,), S, jnp.int32)
+
+    def mix(kind, k, lp, h, state):
+        if kind == MAMBA:
+            y, conv, st = _ssm_prefill_block(cfg, lp["ssm"], h)
+            state["conv"] = jax.lax.dynamic_update_index_in_dim(
+                state["conv"], conv.astype(state["conv"].dtype), k, 0)
+            state["ssm"] = jax.lax.dynamic_update_index_in_dim(
+                state["ssm"], st.astype(state["ssm"].dtype), k, 0)
+            return y, state
+        q, kk, v = _pattern_qkv(cfg, lp["attn"], h, positions)
+        out = blocked_attention(q, kk, v, causal=True,
+                                scale=cfg.attention_multiplier)
+        for name, new in (("k", kk), ("v", v)):
+            state[name] = jax.lax.dynamic_update_slice(
+                state[name], new.reshape(B, S, -1)[None].astype(
+                    state[name].dtype), (k, 0, 0, 0))
+        return out.reshape(B, S, -1) @ lp["attn"]["wo"], state
+
+    state = {n: cache[n] for n in ("conv", "ssm", "k", "v")}
+    x, state = _over_layers(cfg, params, _pattern_embed(cfg, params, tokens),
+                            state, mix)
+    cache.update(state)
+    return x, cache
+
+
+def _write_rows(buf, layer, new, at, active):
+    """``buf`` [L, B, S, W] with each active row b's ``new[b]`` [1, W]
+    written at layer ``layer`` and position ``at[b]``: one in-place
+    update a row."""
+
+    def body(b, buf):
+        start = (layer, b, at[b], 0)
+        old = jax.lax.dynamic_slice(buf, start, (1, 1) + new.shape[1:])
+        row = jax.lax.dynamic_index_in_dim(new, b, 0)[None].astype(buf.dtype)
+        return jax.lax.dynamic_update_slice(
+            buf, jnp.where(active[b], row, old), start)
+
+    return jax.lax.fori_loop(0, new.shape[0], body, buf)
+
+
+def _rows_attention(cfg: ArchConfig, q, kc, vc, valid_len):
+    """One query per row, q [B, 1, Hq, hd], over the row's own first
+    ``valid_len`` [B] positions of keys kc and values vc [B, S, Hkv*hd].
+
+    Both products read the cache as it lies, every head of a position
+    side by side: the queries go in as a block-diagonal [Hkv*hd, Hq]
+    matrix (a query meets only its own head's keys), and of the values'
+    product only each head's own block is kept. That costs Hkv times the
+    (small) operations of per-head products, which would want the cache
+    transposed, a copy of every layer's keys and values a step."""
+    B, _, Hq, hd = q.shape
+    S, W = kc.shape[1], kc.shape[2]
+    Hkv = W // hd
+    G = Hq // Hkv
+    eye = jnp.eye(Hkv, dtype=kc.dtype)
+    qh = q[:, 0].reshape(B, Hkv, G, hd).astype(kc.dtype)
+    qbd = jnp.einsum("bhgd,hj->bhdjg", qh, eye).reshape(B, W, Hq)
+    s = jnp.einsum("bkw,bwc->bkc", kc, qbd,
+                   preferred_element_type=jnp.float32)        # [B, S, Hq]
+    s = s * (hd ** -0.5 if cfg.attention_multiplier is None
+             else cfg.attention_multiplier)
+    valid = jnp.arange(S)[None, :] < valid_len[:, None]
+    s = jnp.where(valid[:, :, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=1)
+    o = jnp.einsum("bkc,bkw->bcw", p.astype(vc.dtype), vc,
+                   preferred_element_type=jnp.float32)        # [B, Hq, W]
+    o = jnp.einsum("bjghd,jh->bjgd", o.reshape(B, Hkv, G, Hkv, hd),
+                   eye.astype(o.dtype))
+    return o.reshape(B, 1, Hq, hd).astype(q.dtype)
+
+
+def _pattern_decode(cfg: ArchConfig, params, token, cache, active=None):
+    """One token for every row. ``active`` [B] bool (default all):
+    inactive rows keep their state and length and their logits are
+    meaningless. A row writes its token's keys and values at its own
+    length, and attends over the positions before it and itself."""
+    B = token.shape[0]
+    pos = cache["len"]
+    if active is None:
+        active = jnp.ones((B,), bool)
+    x = _pattern_embed(cfg, params, token[:, None])
+
+    def keep(new, old):
+        m = active.reshape((B,) + (1,) * (old.ndim - 1))
+        return jnp.where(m, new.astype(old.dtype), old)
+
+    def mix(kind, k, lp, h, state):
+        if kind == MAMBA:
+            conv = jax.lax.dynamic_index_in_dim(state["conv"], k, 0, False)
+            st = jax.lax.dynamic_index_in_dim(state["ssm"], k, 0, False)
+            y, conv2, st2 = ssm_mod.mamba2_decode(
+                lp["ssm"], h[:, 0], conv, st, head_dim=cfg.ssm_head_dim,
+                ssm_state=cfg.ssm_state, dt_limit=cfg.ssm_dt_limit,
+                norm_eps=cfg.norm_eps)
+            state["conv"] = jax.lax.dynamic_update_index_in_dim(
+                state["conv"], keep(conv2, conv), k, 0)
+            state["ssm"] = jax.lax.dynamic_update_index_in_dim(
+                state["ssm"], keep(st2, st), k, 0)
+            return y[:, None], state
+        q, kk, v = _pattern_qkv(cfg, lp["attn"], h, pos[:, None])
+        for name, new in (("k", kk), ("v", v)):
+            state[name] = _write_rows(state[name], k, new.reshape(B, 1, -1),
+                                      pos, active)
+        kc = jax.lax.dynamic_index_in_dim(state["k"], k, 0, False)
+        vc = jax.lax.dynamic_index_in_dim(state["v"], k, 0, False)
+        out = _rows_attention(cfg, q, kc, vc, pos + 1)
+        return out.reshape(B, 1, -1) @ lp["attn"]["wo"], state
+
+    state = {n: cache[n] for n in ("conv", "ssm", "k", "v")}
+    x, state = _over_layers(cfg, params, x, state, mix)
+    new_cache = dict(cache, **state)
+    new_cache["len"] = pos + active.astype(jnp.int32)
+    return pattern_logits(cfg, params, x[:, 0]), new_cache

@@ -107,6 +107,9 @@ SPANS = {
     "repro.decode_step": "one single-chunk decode step call",
     "repro.decode_many": "one batched gather/scatter decode call",
     "repro.decode_replay": "one full-window replay (prefill) call",
+    "repro.zoo.prefill":
+        "a token session primed from its history: the prefill program's "
+        "call, its cache of the session's positions built",
     "repro.slots_insert": "a carry written into a decode lane",
     "repro.slots_extract": "a carry read out of a decode lane",
     "repro.slots_generate": "one fused generate over the decode lanes",

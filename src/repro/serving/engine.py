@@ -35,6 +35,7 @@ from repro.obs import trace as obs_trace
 from repro.obs.trace import _EPOCH as _TRACE_EPOCH
 from repro.obs.trace import FlushSpans as _FlushSpans
 from repro.obs.trace import finish_all as _finish_all
+from repro.serving.forecaster import input_spec, streams
 from repro.serving.telemetry import Telemetry
 
 
@@ -314,11 +315,22 @@ class EngineShard:
         with self._runners_lock:
             runners = list(self._runners.values())
         agg = {"lanes": 0, "active": 0, "inserts": 0, "spills": 0,
-               "expiries": 0}
+               "expiries": 0, "kv_tokens_read": 0, "lane_state_bytes": {}}
         for r in runners:
             for k, v in r.slot_stats().items():
-                agg[k] += v
+                if k == "lane_state_bytes":
+                    for kind, n in v.items():
+                        agg[k][kind] = max(agg[k].get(kind, 0), n)
+                else:
+                    agg[k] += v
         return agg
+
+    def session_lanes(self, model_key: str) -> dict[str, int]:
+        """The decode lane each lane-resident session of ``model_key``
+        occupies (a snapshot; empty before the model's first step)."""
+        with self._runners_lock:
+            runner = self._runners.get(model_key)
+        return runner.lanes() if runner is not None else {}
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "EngineShard":
@@ -567,28 +579,35 @@ class EngineShard:
             fc = (self._ensemble(model_key)
                   if self._ensemble_spec(model_key) is not None
                   else self.registry.get(model_key))
-            if not hasattr(fc, "step") or not fc.feature_dim:
+            if not streams(fc):
                 raise ValueError(
                     f"{model_key!r} does not support incremental session "
-                    f"serving (needs step/init_carry/replay and a feature "
-                    f"dim)")
-            payload = np.asarray(x_t, np.float32)
-            if payload.ndim == 2 and payload.shape[0] == 1:
+                    f"serving (needs step/init_carry/replay, and a cache "
+                    f"that keeps a length per session)")
+            shape, dtype = input_spec(fc)
+            payload = np.asarray(x_t, dtype)
+            if payload.ndim == len(shape) + 1 and payload.shape[0] == 1:
                 payload = payload[0]
-            if payload.shape != (fc.feature_dim,):
+            if payload.shape != shape:
                 raise ValueError(
-                    f"{model_key!r} expects step vectors of shape "
-                    f"[{fc.feature_dim}], got {payload.shape}")
+                    f"{model_key!r} expects step inputs of shape "
+                    f"{list(shape)}, got {list(payload.shape)}")
             if history is not None:
                 # validate HERE, against this caller only: a malformed
                 # history that first blew up inside the flush would fail
                 # every other client's step sharing that fused batch
-                history = np.asarray(history, np.float32)
-                if history.ndim != 2 or history.shape[0] < 1 \
-                        or history.shape[1] != fc.feature_dim:
-                    raise ValueError(
-                        f"history must be [T>=1, {fc.feature_dim}], got "
-                        f"{history.shape}")
+                history = np.asarray(history, dtype)
+                if history.ndim != len(shape) + 1 or history.shape[0] < 1 \
+                        or history.shape[1:] != shape:
+                    want = ", ".join(["T>=1"] + [str(n) for n in shape])
+                    raise ValueError(f"history must be [{want}], got "
+                                     f"{list(history.shape)}")
+            vocab = getattr(fc, "vocab", None)
+            if vocab is not None and any(
+                    a.size and (a.min() < 0 or a.max() >= vocab)
+                    for a in (payload, history) if a is not None):
+                raise ValueError(f"{model_key!r} takes token ids in "
+                                 f"[0, {vocab})")
             if client_id is None:
                 raise ValueError("streaming steps require a client_id "
                                  "(the session key)")
@@ -662,22 +681,24 @@ class EngineShard:
                     self._payload_shape(fc, t), self._payload_dtype(fc))] * b,
                     [t] * b, b, t))
                 n += 1
-        if hasattr(fc, "warm_decode") and fc.feature_dim:
+        if streams(fc):
             # the streaming decode lane: single step, batched flush and
             # miss-replay programs, plus the runner itself (its ctor
-            # pre-compiles the full-window replay) — all off the hot path
-            n += fc.warm_decode()
+            # pre-compiles the full-window replay and the lane programs)
+            # — all off the hot path
+            if hasattr(fc, "warm_decode"):
+                n += fc.warm_decode()
             self._step_runner(model_key)
         return n
 
     # -- batching internals ------------------------------------------------
     @staticmethod
     def _payload_shape(fc, t: int):
-        return (t, fc.feature_dim) if fc.feature_dim else (t,)
+        return (t,) + input_spec(fc)[0]
 
     @staticmethod
     def _payload_dtype(fc):
-        return np.float32 if fc.feature_dim else np.int32
+        return input_spec(fc)[1]
 
     def _padded(self, fc, payloads, lengths, bucket_b: int, bucket_t: int):
         """Stack variable-length payloads into one right-padded batch of

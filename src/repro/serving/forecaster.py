@@ -22,7 +22,9 @@ Two concrete single-model implementations:
 - ``ZooForecaster`` — any ``repro.models.model_zoo`` arch serving
   next-token prediction; the "extreme event" is an anomalously
   surprising continuation (surprisal in the EVT tail), the serving-side
-  analogue of the paper's extreme-event indicator.
+  analogue of the paper's extreme-event indicator. A hybrid by layer
+  pattern (granite-4.0-h) also streams sessions through the decode
+  lanes, its carry the session's decode cache.
 
 Both are calibrated by ``fit_tail`` over a reference score distribution,
 so ``p_extreme`` is comparable across models hosted in one registry.
@@ -70,11 +72,13 @@ class Forecaster(Protocol):
 @runtime_checkable
 class StreamingForecaster(Forecaster, Protocol):
     """A ``Forecaster`` that also serves O(1) streaming: explicit
-    carries (opaque to callers — single models use per-layer (h, c)
-    tuples, ensembles use {member: carry} dicts), history replay, and
-    device-resident decode slots. This is the full contract
-    ``RecurrentSessionRunner`` / ``DecodeSlots`` serving is written
-    against."""
+    carries (opaque pytrees to callers — the LSTM's per-layer (h, c)
+    tuples, a zoo hybrid's decode cache of SSM, conv and key/value
+    state, an ensemble's {member: carry} dict), history replay, and
+    device-resident decode slots. A step's input is one feature vector
+    [F], or one token id where ``feature_dim`` is 0. This is the full
+    contract ``RecurrentSessionRunner`` / ``DecodeSlots`` serving is
+    written against."""
 
     @property
     def decode_width(self) -> int: ...
@@ -88,6 +92,21 @@ class StreamingForecaster(Forecaster, Protocol):
     def step_many(self, xs, carries, donate=None): ...
 
     def replay(self, window, carry=None): ...
+
+
+def input_spec(fc) -> tuple[tuple[int, ...], Any]:
+    """(shape, dtype) of one time step of ``fc``'s input: a float32
+    feature vector [F], or one int32 token id where ``feature_dim`` is
+    0. A window stacks T of them."""
+    F = fc.feature_dim
+    return ((F,), np.float32) if F else ((), np.int32)
+
+
+def streams(fc) -> bool:
+    """Whether ``fc`` serves streaming sessions: the incremental
+    contract, and (a token model) a decode cache with a length per row."""
+    return all(hasattr(fc, a) for a in ("init_carry", "step", "replay")) \
+        and getattr(fc, "streaming", True)
 
 
 # One compiled function set per RNNConfig, shared by every forecaster
@@ -351,17 +370,19 @@ def _alert_probability(score, tail: dict | None, gamma: float, head=None):
 @dataclasses.dataclass
 class DecodeSlots:
     """Device-resident decode slot state: ``num_slots`` lanes of stacked
-    (h, c) carries held as device arrays, plus a host-side active-lane
-    mask. Sessions are written into lanes with ``insert`` (prefill →
-    insert), stepped in place by ``generate`` (one fused dispatch for
-    ALL lanes), and read out only on spill/migration (``extract``).
+    carries held as device arrays, plus a host-side active-lane mask
+    (and, for token models, each lane's session length). Sessions are
+    written into lanes with ``insert`` (prefill → insert), stepped in
+    place by ``generate`` (one fused dispatch for ALL lanes), and read
+    out only on spill/migration (``extract``).
     ``num_slots`` is always a multiple of the owning forecaster's
     ``decode_width`` — ``init_slots`` rounds up — so generate can chunk
     the state at the lane width with no partial chunk."""
 
-    carry: PyTree                # [num_slots, H]-stacked per layer
+    carry: PyTree                # the carries, stacked on a lane axis
     num_slots: int
     active: Any                  # np.ndarray bool [num_slots], host-side
+    lengths: Any = None          # np.ndarray int [num_slots], host-side
 
     @property
     def n_active(self) -> int:
@@ -704,9 +725,131 @@ class LSTMForecaster:
 
 
 @dataclasses.dataclass
+class LogitProbe:
+    """An audit of the distribution a ``ZooForecaster`` serves, without
+    reading back [rows, vocab] logits: the logits of vocabulary ``ids``
+    [K], with the maximum and the log-sum-exp over the whole vocabulary,
+    at every position a session's prefill computes and for every lane a
+    generate steps. ``sink(kind, key, (logits [..., K], max [...], lse
+    [...]))`` receives them as device arrays, before any readback:
+    ``kind`` "prefill" with ``key`` the history's token ids (host int32
+    [T]; arrays over its T positions), or "generate" with ``key`` the
+    lanes stepped (arrays over every lane of the slot state)."""
+
+    ids: Any
+    sink: Any
+
+
+def _zoo_answer(logits, vocab: int, xi, scale, active, gamma):
+    """(greedy next token, alert, max, log-sum-exp) of logits [..., V]:
+    the alert is the EVT tail probability of the surprisal of the greedy
+    token, lse - max."""
+    logits = logits[..., :vocab]
+    mx = logits.max(-1)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return tok, _fused_alert(lse - mx, None, xi, scale, active, gamma), \
+        mx, lse
+
+
+def _lane_map(fn, *trees):
+    """``fn(axis, *leaves)`` over a layer-pattern cache, on each entry's
+    lane axis."""
+    from repro.models.transformer import cache_lane_axis
+
+    return {name: fn(cache_lane_axis(name), *(t[name] for t in trees))
+            for name in trees[0]}
+
+
+def _build_zoo_stream_fns(cfg, max_len: int, probed: bool):
+    """The session programs of a layer-pattern hybrid, lanes of
+    ``max_len`` positions: prefill of a history, one decode step of a
+    batch of sessions, and the decode lanes' insert, extract and fused
+    generate. ``probed``: the prefill and generate also return the
+    ``LogitProbe`` outputs."""
+    from repro.models import transformer as tfm
+
+    V = cfg.vocab
+
+    def probe_positions(params, h, ids):
+        # [T, d] final hidden states -> probe outputs at every position,
+        # 256 positions of logits [., V] at a time
+        T, d = h.shape
+        blk = min(T, 256)
+        hp = jnp.pad(h, ((0, -T % blk), (0, 0))).reshape(-1, blk, d)
+
+        def one(hb):
+            lg = tfm.pattern_logits(cfg, params, hb)[:, :V]
+            return (jnp.take(lg, ids, axis=1), lg.max(-1),
+                    jax.nn.logsumexp(lg, axis=-1))
+
+        lg, mx, lse = jax.lax.map(one, hp)
+        return lg.reshape(-1, ids.shape[0])[:T], mx.reshape(-1)[:T], \
+            lse.reshape(-1)[:T]
+
+    def prefill(params, tokens, ids, xi, scale, active, gamma):
+        h, cache = tfm.pattern_prefill(cfg, params, tokens, max_len)
+        tok, p, _mx, _lse = _zoo_answer(
+            tfm.pattern_logits(cfg, params, h[:, -1]), V, xi, scale, active,
+            gamma)
+        probe = probe_positions(params, h[0], ids) if probed else None
+        return tok, p, probe, cache
+
+    def decode(params, tokens, cache, xi, scale, active, gamma):
+        logits, cache = tfm.lm_decode_step(cfg, params, tokens, cache)
+        tok, p, _mx, _lse = _zoo_answer(logits, V, xi, scale, active, gamma)
+        return tok, p, cache
+
+    def slots_generate(params, tokens, slot_cache, step_mask, ids, xi, scale,
+                       active, gamma):
+        logits, slot_cache = tfm.lm_decode_step(cfg, params, tokens,
+                                                slot_cache, active=step_mask)
+        tok, p, mx, lse = _zoo_answer(logits, V, xi, scale, active, gamma)
+        probe = (jnp.take(logits[:, :V], ids, axis=1), mx, lse) \
+            if probed else None
+        return tok, p, probe, slot_cache
+
+    def slots_insert(slot_cache, carry, lane):
+        return _lane_map(
+            lambda ax, s, row: jax.lax.dynamic_update_slice_in_dim(
+                s, row.astype(s.dtype), lane, ax), slot_cache, carry)
+
+    def slots_extract(slot_cache, lane):
+        return _lane_map(
+            lambda ax, s: jax.lax.dynamic_slice_in_dim(s, lane, 1, ax),
+            slot_cache)
+
+    return {
+        "prefill": jax.jit(prefill, static_argnames=("gamma",)),
+        "decode": jax.jit(decode, static_argnames=("gamma",)),
+        "slots_generate": jax.jit(slots_generate,
+                                  static_argnames=("gamma",)),
+        # the lanes' state (weights aside, most of the chip's memory) is
+        # donated in and out: a wave updates it in place
+        "slots_generate_donate": jax.jit(slots_generate,
+                                         static_argnames=("gamma",),
+                                         donate_argnums=(2,)),
+        "slots_insert": jax.jit(slots_insert),
+        "slots_insert_donate": jax.jit(slots_insert, donate_argnums=(0,)),
+        "slots_extract": jax.jit(slots_extract),
+    }
+
+
+@dataclasses.dataclass
 class ZooForecaster:
     """Any model-zoo arch behind the serving interface: forecast is the
-    greedy next token; extreme probability is EVT-calibrated surprisal."""
+    greedy next token; extreme probability is EVT-calibrated surprisal
+    (of that token: log-sum-exp minus the largest logit).
+
+    A hybrid by layer pattern (``cfg.layer_types``, granite-4.0-h) also
+    serves streaming sessions through the decode lanes, as the LSTM
+    does: its carry is the session's decode cache (SSM and conv state
+    per Mamba2 layer, keys and values of ``window`` positions per
+    attention layer, and the session's length), a step's input is one
+    token id, a lane miss primes from the history by prefill, one
+    ``generate`` steps every lane, and ``extract`` spills a lane's cache
+    to host memory. Other archs keep one length for a whole batch, so
+    they serve ``predict`` only (``streaming`` is False)."""
 
     cfg: Any                     # repro.configs.base.ArchConfig
     params: PyTree
@@ -714,6 +857,10 @@ class ZooForecaster:
     gamma: float = 5.0
     version: int = 0
     published_at: float | None = None
+    # the serving context: predict's default length bucket and, for
+    # streaming, the positions a lane holds (a session's longest length)
+    window: int = 32
+    probe: LogitProbe | None = None
     kind: str = dataclasses.field(default="zoo", init=False)
 
     def __post_init__(self):
@@ -740,14 +887,30 @@ class ZooForecaster:
             return tok.astype(jnp.int32), surprisal
 
         self._fwd = jax.jit(_fwd)
-
-    @property
-    def window(self) -> int:
-        return 32                # default serving context bucket
+        if self.streaming:
+            self._fns = _build_zoo_stream_fns(self.cfg, self.window,
+                                              self.probe is not None)
+            self._ids = jnp.asarray(
+                self.probe.ids if self.probe is not None else (), jnp.int32)
 
     @property
     def feature_dim(self) -> int:
-        return 0                 # token ids, no feature axis
+        """0: the input is token ids, one int32 id a step, [T] a window."""
+        return 0
+
+    @property
+    def streaming(self) -> bool:
+        """Whether sessions stream through the decode lanes: the decode
+        cache keeps a length per row (the layer-pattern hybrid's)."""
+        return bool(self.cfg.layer_types)
+
+    @property
+    def decode_width(self) -> int:
+        return 1                 # one generate steps every lane
+
+    @property
+    def vocab(self) -> int:
+        return self.cfg.vocab
 
     def predict(self, windows, lengths=None):
         """windows int32 [B, T] token ids (right-padded). Returns
@@ -782,6 +945,198 @@ class ZooForecaster:
         clone.published_at = None
         return clone
 
+    # -- streaming sessions (layer-pattern hybrids) ------------------------
+    def _tail_args(self):
+        if self.tail is None:
+            return 0.0, 1.0, False
+        return float(self.tail["xi"]), float(self.tail["scale"]), True
+
+    def _require_streaming(self):
+        if not self.streaming:
+            raise TypeError(f"{self.cfg.name} keeps one cache length per "
+                            f"batch: it serves predict only")
+
+    def _cache_shapes(self, batch: int):
+        from repro.models import transformer as tfm
+
+        return jax.eval_shape(lambda: tfm.init_cache(self.cfg, batch,
+                                                     self.window))
+
+    def init_carry(self, batch: int = 1):
+        """Empty sessions: the decode cache of ``batch`` rows, length 0."""
+        self._require_streaming()
+        from repro.models import transformer as tfm
+
+        carry = tfm.init_cache(self.cfg, batch, self.window)
+        carry["len"] = np.zeros((batch,), np.int32)
+        return carry
+
+    def lane_state_bytes(self) -> dict:
+        """One session's carry in bytes, by kind of state: ``ssm`` (the
+        SSM states), ``conv`` (the conv inputs), ``kv`` (keys and values
+        of every attention layer) and ``len``."""
+        shapes = self._cache_shapes(1)
+        nbytes = {k: int(np.prod(a.shape)) * a.dtype.itemsize
+                  for k, a in shapes.items()}
+        return {"ssm": nbytes["ssm"], "conv": nbytes["conv"],
+                "kv": nbytes["k"] + nbytes["v"], "len": nbytes["len"]}
+
+    def carry_nbytes(self, batch: int = 1) -> int:
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in self._cache_shapes(batch).values())
+
+    def _tokens(self, x, shape) -> np.ndarray:
+        x = np.asarray(x, np.int32).reshape(shape)
+        if x.size and (x.min() < 0 or x.max() >= self.cfg.vocab):
+            raise ValueError(f"token ids must lie in [0, {self.cfg.vocab})")
+        return x
+
+    def prefill(self, window, carry=None):
+        """A session's history, int32 [1, T] token ids, run through the
+        model from empty state: (next token [1] as float32, p_extreme
+        [1], the session's carry of T positions). With a ``probe``, its
+        sink receives every position's probe outputs."""
+        self._require_streaming()
+        if carry is not None:
+            raise ValueError("a history primes a session from empty state")
+        tokens = np.asarray(window, np.int32)
+        if tokens.ndim != 2 or tokens.shape[0] != 1:
+            raise ValueError(f"prefill takes one history [1, T], got "
+                             f"{tokens.shape}")
+        tokens = self._tokens(tokens, tokens.shape)
+        T = tokens.shape[1]
+        dispatch.record("prefill", batch=1, hidden=self.cfg.d_model)
+        with obs_trace.span("repro.zoo.prefill"):
+            tok, p, probe, carry = self._fns["prefill"](
+                self.params, tokens, self._ids, *self._tail_args(),
+                gamma=float(self.gamma))
+        carry["len"] = np.full((1,), T, np.int32)
+        if self.probe is not None:
+            self.probe.sink("prefill", tokens[0], probe)
+        with obs_trace.span("repro.readback"):
+            return np.asarray(tok, np.float32), np.asarray(p), carry
+
+    replay = prefill
+
+    def step(self, x_t, carry):
+        """One token for each row of a batched carry: x_t int32 [B].
+        Returns (next token [B] as float32, p_extreme [B], carry)."""
+        self._require_streaming()
+        tokens = self._tokens(x_t, (-1,))
+        tok, p, carry = self._fns["decode"](self.params, tokens, carry,
+                                            *self._tail_args(),
+                                            gamma=float(self.gamma))
+        return np.asarray(tok, np.float32), np.asarray(p), carry
+
+    def step_many(self, xs, carries, donate=None):
+        """One token for each of N sessions: their batch-1 carries are
+        stacked on the lane axis, stepped in one program and split."""
+        stacked = _lane_map(lambda ax, *rows: jnp.concatenate(rows, ax),
+                            *carries)
+        tok, p, out = self.step(xs, stacked)
+        split = [_lane_map(lambda ax, a, i=i: jax.lax.slice_in_dim(
+            a, i, i + 1, axis=ax), out) for i in range(len(carries))]
+        return tok, p, split
+
+    # -- decode lanes ------------------------------------------------------
+    def init_slots(self, num_slots: int) -> DecodeSlots:
+        """``num_slots`` lanes of empty sessions, on the device."""
+        self._require_streaming()
+        if num_slots < 1:
+            raise ValueError(f"num_slots must be >= 1, got {num_slots}")
+        from repro.models import transformer as tfm
+
+        return DecodeSlots(carry=tfm.init_cache(self.cfg, num_slots,
+                                                self.window),
+                           num_slots=num_slots,
+                           active=np.zeros((num_slots,), bool),
+                           lengths=np.zeros((num_slots,), np.int64))
+
+    def insert(self, slots: DecodeSlots, lane: int, carry,
+               donate: bool | None = None) -> DecodeSlots:
+        """Write a batch-1 carry (on the device or spilled to host) into
+        ``lane``; with donation the lane state is updated in place."""
+        donate = _donate_default() if donate is None \
+            else (donate and jax.default_backend() != "cpu")
+        dispatch.record("slots_insert", batch=1, hidden=self.cfg.d_model)
+        fn = self._fns["slots_insert_donate" if donate else "slots_insert"]
+        with obs_trace.span("repro.slots_insert"):
+            slots.carry = fn(slots.carry, carry, jnp.int32(lane))
+        slots.active[lane] = True
+        slots.lengths[lane] = int(np.asarray(carry["len"]).reshape(-1)[0])
+        return slots
+
+    def extract(self, slots: DecodeSlots, lane: int):
+        """``lane``'s carry, copied to host memory (a spilled session's
+        cache is the size of the lane, too large to stay on the device);
+        the lane is left intact."""
+        dispatch.record("slots_extract", batch=1, hidden=self.cfg.d_model)
+        with obs_trace.span("repro.slots_extract"):
+            return jax.device_get(self._fns["slots_extract"](
+                slots.carry, jnp.int32(lane)))
+
+    def release(self, slots: DecodeSlots, lane: int) -> None:
+        slots.active[lane] = False
+
+    def generate(self, slots: DecodeSlots, x, lanes=None,
+                 donate: bool | None = None):
+        """One token for each lane in ``lanes`` (default: every active
+        lane), in one program over the whole lane state: x int32
+        [num_slots] (rows of other lanes are ignored; those lanes keep
+        their state). Returns (next token [num_slots] as float32,
+        p_extreme [num_slots], slots); read only the rows of ``lanes``.
+        A lane whose session fills its ``window`` positions is refused."""
+        donate = _donate_default() if donate is None \
+            else (donate and jax.default_backend() != "cpu")
+        S = slots.num_slots
+        x = self._tokens(x, (-1,))
+        if x.shape != (S,):
+            raise ValueError(f"generate expects x [{S}], got {x.shape}")
+        mask = np.zeros((S,), bool)
+        if lanes is None:
+            mask[:] = slots.active
+        else:
+            mask[np.asarray(lanes, np.int64)] = True
+        stepped = np.flatnonzero(mask)
+        full = stepped[slots.lengths[stepped] >= self.window]
+        if len(full):
+            raise ValueError(f"lanes {full.tolist()} hold sessions of "
+                             f"{self.window} positions, the most a lane "
+                             f"holds")
+        dispatch.record("slots_generate", batch=S, hidden=self.cfg.d_model)
+        fn = self._fns["slots_generate_donate" if donate
+                       else "slots_generate"]
+        with obs_trace.span("repro.slots_generate"):
+            tok, p, probe, carry = fn(self.params, x, slots.carry, mask,
+                                      self._ids, *self._tail_args(),
+                                      gamma=float(self.gamma))
+        slots.carry = carry
+        slots.lengths[stepped] += 1
+        if self.probe is not None:
+            self.probe.sink("generate", stepped.tolist(), probe)
+        with obs_trace.span("repro.readback"):
+            return np.asarray(tok, np.float32), np.asarray(p), slots
+
+    def warm_slots(self, num_slots: int) -> int:
+        """Compile the lane programs (insert and generate, plain and
+        donating, and extract) for ``num_slots`` lanes from their shapes
+        alone: no second lane state is allocated. Returns #programs."""
+        S = num_slots
+        shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+        params = jax.tree.map(shape, self.params)
+        lanes, one = self._cache_shapes(S), self._cache_shapes(1)
+        lane = jax.ShapeDtypeStruct((), jnp.int32)
+        tail = self._tail_args()
+        for name in ("slots_insert", "slots_insert_donate"):
+            self._fns[name].lower(lanes, one, lane).compile()
+        self._fns["slots_extract"].lower(lanes, lane).compile()
+        for name in ("slots_generate", "slots_generate_donate"):
+            self._fns[name].lower(
+                params, jax.ShapeDtypeStruct((S,), jnp.int32), lanes,
+                jax.ShapeDtypeStruct((S,), jnp.bool_), self._ids, *tail,
+                gamma=float(self.gamma)).compile()
+        return 5
+
 
 def build_lstm_forecaster(seed: int = 0, cfg: RNNConfig | None = None,
                           params: PyTree | None = None,
@@ -805,6 +1160,10 @@ def build_lstm_forecaster(seed: int = 0, cfg: RNNConfig | None = None,
 
 def build_zoo_forecaster(arch: str, seed: int = 0, reduced: bool = True,
                          calibrate_batch: int = 8) -> ZooForecaster:
+    """A zoo arch behind the serving interface, freshly initialised from
+    ``seed``: its ``reduced`` smoke shapes or (``reduced=False``) its
+    published widths, calibrated on ``calibrate_batch`` synthetic
+    windows of its ``window`` (0: not calibrated)."""
     from repro.configs import get_config
     from repro.configs.base import reduced as reduce_cfg
     from repro.data.tokens import synthetic_token_batch

@@ -363,6 +363,7 @@ class ModelRegistry:
             name = fc.cfg.name
             meta["reduced"] = name.endswith("-smoke")
             meta["arch"] = name[:-len("-smoke")] if meta["reduced"] else name
+            meta["window"] = fc.window
         else:
             raise ValueError(f"cannot persist forecaster kind {fc.kind!r}")
         return fc, meta
@@ -407,7 +408,8 @@ class ModelRegistry:
                 params = jax.device_put(params)
             fc = ZooForecaster(cfg=acfg, params=params,
                                tail=meta.get("tail"),
-                               gamma=meta.get("gamma", 5.0))
+                               gamma=meta.get("gamma", 5.0),
+                               window=meta.get("window", 32))
         else:
             raise ValueError(f"{origin}: unknown forecaster kind {kind!r}")
         fc.version = int(meta.get("version", 0))

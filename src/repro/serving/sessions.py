@@ -31,8 +31,10 @@ assignment laws — only the clients the rendezvous hash moves (to an
 arriving shard, or off a departing one) are migrated, carries intact,
 and the fleet budget is re-split over the new shard set.
 
-Carries are OPAQUE throughout: single models store per-layer (h, c)
-tuples; an ``EnsembleForecaster`` session stores one composite
+Carries are OPAQUE throughout: the LSTM stores per-layer (h, c)
+tuples, a zoo hybrid its decode cache (SSM, conv and key/value state of
+about 110 MB at published widths, spilled to host memory); an
+``EnsembleForecaster`` session stores one composite
 ``{member_key: member_carry}`` dict under ONE client id. The runner
 never looks inside — init/step/replay/extract on the ensemble build and
 split the dict — so a composite session spills, migrates and re-homes
@@ -51,6 +53,7 @@ from collections import OrderedDict
 from typing import Any
 
 from repro.obs import trace as obs_trace
+from repro.serving.forecaster import input_spec, streams
 from repro.serving.router import ConsistentRouter
 from repro.serving.telemetry import Telemetry
 
@@ -549,8 +552,7 @@ class RecurrentSessionRunner:
         self.reprimes = 0            # carries replayed onto new weights
         self.carried_across_swap = 0  # carries reused without history
         # -- device-resident decode slots --------------------------------
-        slot_capable = hasattr(fc, "init_slots") \
-            and getattr(fc, "feature_dim", 0)
+        slot_capable = hasattr(fc, "init_slots") and streams(fc)
         if num_slots is None:
             # a ShardedSessionCache's contract is that carries live in
             # per-shard caches and FOLLOW mesh membership — runner-local
@@ -574,6 +576,12 @@ class RecurrentSessionRunner:
         self.slot_inserts = 0        # sessions written into a lane
         self.slot_spills = 0         # lane carries spilled to the cache
         self.slot_expiries = 0       # lanes freed by TTL (state dropped)
+        # one lane's carry in bytes by kind of state, and the positions
+        # the generate waves' stepped lanes held (attention reads them)
+        self.lane_state_bytes = (fc.lane_state_bytes()
+                                 if hasattr(fc, "lane_state_bytes")
+                                 else {"carry": self._nbytes})
+        self.kv_tokens_read = 0
         window = getattr(fc, "window", None)
         if window and getattr(fc, "feature_dim", 0):
             import numpy as np
@@ -582,10 +590,10 @@ class RecurrentSessionRunner:
             # serving path — otherwise the first cache miss / swap
             # re-prime pays the jit compile at serve time
             fc.replay(np.zeros((1, window, fc.feature_dim), np.float32))
-            if self._slots is not None:
-                # same deal for the slot lifecycle programs: the first
-                # flush must not pay the generate compile at serve time
-                fc.warm_slots(self.num_slots)
+        if self._slots is not None:
+            # same deal for the slot lifecycle programs: the first
+            # flush must not pay the generate compile at serve time
+            fc.warm_slots(self.num_slots)
 
     def _resolve(self):
         fc = self._provider() if self._provider is not None \
@@ -635,7 +643,7 @@ class RecurrentSessionRunner:
             return None
         import numpy as np
 
-        hist = np.asarray(history, np.float32)
+        hist = np.asarray(history, input_spec(fc)[1])
         window = getattr(fc, "window", None)
         if window and hist.shape[0] > window:
             # clamp to the newest `window` steps: the serving
@@ -664,9 +672,8 @@ class RecurrentSessionRunner:
             return self.step_many([(client_id, x_t, history)])[0]
         fc = self._resolve()
         version = getattr(fc, "version", 0)
-        x_t = np.asarray(x_t, np.float32)
-        if x_t.ndim == 1:
-            x_t = x_t[None, :]
+        shape, dtype = input_spec(fc)
+        x_t = np.asarray(x_t, dtype).reshape((1,) + shape)
         hist = self._clamp_history(fc, history)
         carry, stamp = self._resolve_carry(fc, client_id, hist, version)
         y, p, carry = fc.step(x_t, carry)
@@ -841,14 +848,14 @@ class RecurrentSessionRunner:
                                results) -> None:
         import numpy as np
 
-        xs = np.zeros((self.num_slots, fc.feature_dim), np.float32)
+        shape, dtype = input_spec(fc)
+        xs = np.zeros((self.num_slots,) + shape, dtype)
         lanes = []
         for idx in chunk:
             cid, x_t, history = items[idx]
-            x_t = np.asarray(x_t, np.float32)
             hist = self._clamp_history(fc, history)
             lane = self._ensure_resident_locked(fc, cid, hist, version)
-            xs[lane] = x_t[0] if x_t.ndim == 2 else x_t
+            xs[lane] = np.asarray(x_t, dtype).reshape(shape)
             lanes.append(lane)
         try:
             ys, ps, _ = fc.generate(self._slots, xs, lanes=lanes,
@@ -860,6 +867,9 @@ class RecurrentSessionRunner:
                 # every resident session, so reset the whole plane
                 self._reset_slots_locked(fc)
             raise
+        lengths = getattr(self._slots, "lengths", None)
+        if lengths is not None:
+            self.kv_tokens_read += int(lengths[lanes].sum())
         for row, idx in enumerate(chunk):
             results[idx] = (float(ys[lanes[row]]), float(ps[lanes[row]]))
 
@@ -902,24 +912,31 @@ class RecurrentSessionRunner:
         with self._slots_lock:
             return list(self._lanes)
 
+    def lanes(self) -> dict[str, int]:
+        """The lane of each lane-resident session (a snapshot)."""
+        with self._slots_lock:
+            return dict(self._lanes)
+
     def slot_stats(self) -> dict:
         with self._slots_lock:
             return {"lanes": self.num_slots,
                     "active": len(self._lanes),
                     "inserts": self.slot_inserts,
                     "spills": self.slot_spills,
-                    "expiries": self.slot_expiries}
+                    "expiries": self.slot_expiries,
+                    "kv_tokens_read": self.kv_tokens_read,
+                    "lane_state_bytes": dict(self.lane_state_bytes)}
 
     def _run_waves(self, fc, items, waves, version, results) -> None:
         import numpy as np
 
+        shape, dtype = input_spec(fc)
         for wave in waves:
-            xs = np.zeros((len(wave), fc.feature_dim), np.float32)
+            xs = np.zeros((len(wave),) + shape, dtype)
             carries, stamps = [], []
             for row, idx in enumerate(wave):
                 cid, x_t, history = items[idx]
-                x_t = np.asarray(x_t, np.float32)
-                xs[row] = x_t[0] if x_t.ndim == 2 else x_t
+                xs[row] = np.asarray(x_t, dtype).reshape(shape)
                 hist = self._clamp_history(fc, history)
                 carry, stamp = self._resolve_carry(fc, cid, hist, version)
                 carries.append(carry)

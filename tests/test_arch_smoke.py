@@ -15,7 +15,7 @@ from repro.models.model_zoo import build_model
 from repro.optim.optimizers import adam, apply_updates
 
 ALL_ARCHS = list_archs()
-assert len(ALL_ARCHS) == 10
+assert len(ALL_ARCHS) == 11
 
 
 def _inputs(cfg, batch=2, seq=24, seed=0):

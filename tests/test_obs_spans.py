@@ -161,6 +161,53 @@ def test_one_lane_miss_span_per_lane_insert(forecaster, spans):
                for m in misses) == runner.slot_spills
 
 
+@pytest.fixture(scope="module")
+def zoo():
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import reduced
+    from repro.models.transformer import init_lm
+    from repro.serving import ZooForecaster
+
+    cfg = dataclasses.replace(reduced(get_config("granite-4.0-h-micro")),
+                              d_model=64, n_heads=2, n_kv_heads=1,
+                              head_dim=32, d_ff=128, vocab=256,
+                              ssm_state=16, ssm_head_dim=16)
+    return ZooForecaster(cfg=cfg, params=init_lm(cfg, jax.random.PRNGKey(0)),
+                         window=16)
+
+
+def test_zoo_session_steps_open_the_slot_spans(zoo, spans):
+    """A token session's step flush runs the lanes as the LSTM's does; a
+    lane miss primes from the history under ``repro.zoo.prefill``, and
+    on one lane the second client's miss spills the first."""
+    reg = ModelRegistry()
+    reg.register("z", zoo)
+    cfg = BatcherConfig(max_batch=4, max_wait_ms=1.0, decode_slots=1)
+    with ServingEngine(reg, cfg) as eng:
+        eng.warmup("z", lengths=(8,))
+        eng.step("z", "a", 3, history=np.arange(5))
+        eng.step("z", "b", 4, history=np.arange(6))
+        eng.step("z", "b", 5)
+        stats = eng.slot_stats()
+    assert stats["inserts"] == 2 and stats["spills"] == 1
+    assert stats["kv_tokens_read"] == 6 + 7 + 8
+    flushes = spans.trees("repro.engine.flush.step")
+    assert len(flushes) == 3
+    many = [f[1][0] for f in flushes]
+    assert [names(m) for m in many] == [
+        ["repro.sessions.lane_miss", "repro.slots_generate",
+         "repro.readback"]] * 2 + [["repro.slots_generate",
+                                    "repro.readback"]]
+    assert names(many[0][1][0]) == ["repro.zoo.prefill", "repro.readback",
+                                    "repro.slots_insert"]
+    assert names(many[1][1][0]) == ["repro.zoo.prefill", "repro.readback",
+                                    "repro.slots_extract",
+                                    "repro.slots_insert"]
+    assert spans.count("repro.zoo.prefill") == stats["inserts"]
+
+
 def quad_loss(params, batch):
     x, y = batch
     return jnp.mean((x @ params["w"] - y) ** 2)
