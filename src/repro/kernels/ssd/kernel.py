@@ -96,3 +96,63 @@ def ssd_pallas(xd, a, B_, C_, *, chunk: int = 128, interpret: bool = True):
         interpret=interpret,
     )(xd, a, B_, C_)
     return y, state
+
+
+def _decode_kernel(k_ref, mask_ref, decay_ref, xd_ref, b_ref, c_ref,
+                   s_ref, yt_ref, s_out_ref):
+    del k_ref                                         # used by index_map
+    step = mask_ref[pl.program_id(0)] != 0
+    b_row = b_ref[0]                                  # [1, N]
+    c_row = c_ref[0]                                  # [1, N]
+    for h in range(s_ref.shape[2]):                   # heads, unrolled
+        s = s_ref[0, 0, h]                            # [P, N]
+        new = (s.astype(jnp.float32) * decay_ref[0, :, h:h + 1]
+               + xd_ref[0, :, h:h + 1] * b_row)
+        s_out_ref[0, 0, h] = jnp.where(step, new.astype(s.dtype), s)
+        yt_ref[0, :, h:h + 1] = jnp.sum(new * c_row, axis=1, keepdims=True)
+
+
+def ssd_decode_pallas(states, k, mask, decay, xd_t, B_t, C_t, *,
+                      interpret: bool = True):
+    """One decode step of layer ``k`` of a stacked SSM state, in place.
+
+    states [L, B, H, P, N], aliased to the returned state; k int32
+    scalar; mask [B] int32 (0: the lane keeps its state); decay
+    [B, 1, H] (exp(dt*A)); xd_t [B, P, H] (dt*x, heads on lanes); B_t,
+    C_t [B, 1, N]; all float32 but the states, which are computed in
+    float32 and stored in their own dtype. Returns (y [B, P, H]
+    float32, states): the stepped lanes' y and the states with layer k
+    of those lanes stepped. One grid step is one lane's whole layer-k
+    state, read and written once; the other layers are never
+    touched."""
+    L, Bsz, H, P, N = states.shape
+    lane = lambda b, k, m: (b, 0, 0)                  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(Bsz,),
+        in_specs=[
+            pl.BlockSpec((1, 1, H), lane),
+            pl.BlockSpec((1, P, H), lane),
+            pl.BlockSpec((1, 1, N), lane),
+            pl.BlockSpec((1, 1, N), lane),
+            pl.BlockSpec((1, 1, H, P, N),
+                         lambda b, k, m: (k[0], b, 0, 0, 0)),
+        ],
+        out_specs=(
+            pl.BlockSpec((1, P, H), lane),
+            pl.BlockSpec((1, 1, H, P, N),
+                         lambda b, k, m: (k[0], b, 0, 0, 0)),
+        ),
+    )
+    return pl.pallas_call(
+        _decode_kernel,
+        out_shape=(jax.ShapeDtypeStruct((Bsz, P, H), jnp.float32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)),
+        grid_spec=grid_spec,
+        # operands count the two scalar-prefetch arguments: 6 = states
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(jnp.reshape(k, (1,)).astype(jnp.int32), mask.astype(jnp.int32),
+      decay, xd_t, B_t, C_t, states)

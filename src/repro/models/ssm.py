@@ -22,6 +22,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ssd.ops import ssd_decode_inplace
 from repro.models.layers import silu, rms_norm
 
 
@@ -183,10 +184,11 @@ def mamba2_apply(p, x, *, head_dim: int, ssm_state: int, chunk: int = 128,
     return y @ p["out_proj"]
 
 
-def mamba2_decode(p, x_t, conv_state, ssm_state_arr, *, head_dim: int,
-                  ssm_state: int, dt_limit=(1e-4, 1e2),
-                  norm_eps: float = 1e-6):
-    """One-token decode. x_t [B, D]. Returns (y [B, D], conv_state, state)."""
+def _mamba2_decode_in(p, x_t, conv_state, *, head_dim: int, ssm_state: int,
+                      dt_limit):
+    """The decode step up to the SSM update: the projection, the conv
+    step and dt. Returns (z, xs [B, H, P], xd_t, a_t, B_t, C_t,
+    conv_state)."""
     Bsz, D = x_t.shape
     d_inner = p["out_proj"].shape[0]
     H = d_inner // head_dim
@@ -206,9 +208,40 @@ def mamba2_decode(p, x_t, conv_state, ssm_state_arr, *, head_dim: int,
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     a_t = dt * A[None, :]
     xd_t = xs * dt[..., None].astype(xs.dtype)
+    return z, xs, xd_t, a_t, B_, C_, conv_state
 
-    y, ssm_state_arr = ssd_decode_step(ssm_state_arr, xd_t, a_t, B_, C_)
+
+def _mamba2_decode_out(p, y, xs, z, norm_eps):
+    """The decode step after the SSM update: D skip, gated norm, out
+    projection."""
     y = y + xs * p["D"][None, :, None]
-    y = y.reshape(Bsz, d_inner)
+    y = y.reshape(y.shape[0], -1)
     y = rms_norm(y * silu(z), p["norm_w"], norm_eps)
-    return y @ p["out_proj"], conv_state, ssm_state_arr
+    return y @ p["out_proj"]
+
+
+def mamba2_decode(p, x_t, conv_state, ssm_state_arr, *, head_dim: int,
+                  ssm_state: int, dt_limit=(1e-4, 1e2),
+                  norm_eps: float = 1e-6):
+    """One-token decode. x_t [B, D]. Returns (y [B, D], conv_state, state)."""
+    z, xs, xd_t, a_t, B_, C_, conv_state = _mamba2_decode_in(
+        p, x_t, conv_state, head_dim=head_dim, ssm_state=ssm_state,
+        dt_limit=dt_limit)
+    y, ssm_state_arr = ssd_decode_step(ssm_state_arr, xd_t, a_t, B_, C_)
+    return (_mamba2_decode_out(p, y, xs, z, norm_eps), conv_state,
+            ssm_state_arr)
+
+
+def mamba2_decode_stacked(p, x_t, conv_state, states, k, mask, *,
+                          head_dim: int, ssm_state: int,
+                          dt_limit=(1e-4, 1e2), norm_eps: float = 1e-6):
+    """``mamba2_decode`` of layer ``k`` of a stacked SSM state
+    [L, B, H, P, N], updated in place by the Pallas kernel
+    ``ssd_decode_inplace``: lanes outside ``mask`` [B] keep their state.
+    Returns (y [B, D], conv_state, states)."""
+    z, xs, xd_t, a_t, B_, C_, conv_state = _mamba2_decode_in(
+        p, x_t, conv_state, head_dim=head_dim, ssm_state=ssm_state,
+        dt_limit=dt_limit)
+    y, states = ssd_decode_inplace(states, k, mask, a_t, xd_t, B_, C_)
+    return (_mamba2_decode_out(p, y, xs, z, norm_eps), conv_state,
+            states)

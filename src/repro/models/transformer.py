@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels.ssd import ops as ssd_ops
 from repro.models.attention import blocked_attention, decode_attention
 from repro.models.layers import (
     apply_norm,
@@ -1006,6 +1007,13 @@ def _rows_attention(cfg: ArchConfig, q, kc, vc, valid_len):
     return o.reshape(B, 1, Hq, hd).astype(q.dtype)
 
 
+def ssm_decode_inplace(cfg: ArchConfig) -> bool:
+    """Whether the layer-pattern decode, traced now, updates its Mamba2
+    layers' stacked SSM state in place (the Pallas kernel, on a TPU)
+    instead of slicing each layer's state out and writing it back."""
+    return MAMBA in cfg.layer_types and ssd_ops.decode_inplace()
+
+
 def _pattern_decode(cfg: ArchConfig, params, token, cache, active=None):
     """One token for every row. ``active`` [B] bool (default all):
     inactive rows keep their state and length and their logits are
@@ -1021,18 +1029,26 @@ def _pattern_decode(cfg: ArchConfig, params, token, cache, active=None):
         m = active.reshape((B,) + (1,) * (old.ndim - 1))
         return jnp.where(m, new.astype(old.dtype), old)
 
+    ssm_kw = dict(head_dim=cfg.ssm_head_dim, ssm_state=cfg.ssm_state,
+                  dt_limit=cfg.ssm_dt_limit, norm_eps=cfg.norm_eps)
+    inplace = ssm_decode_inplace(cfg)
+
     def mix(kind, k, lp, h, state):
         if kind == MAMBA:
             conv = jax.lax.dynamic_index_in_dim(state["conv"], k, 0, False)
-            st = jax.lax.dynamic_index_in_dim(state["ssm"], k, 0, False)
-            y, conv2, st2 = ssm_mod.mamba2_decode(
-                lp["ssm"], h[:, 0], conv, st, head_dim=cfg.ssm_head_dim,
-                ssm_state=cfg.ssm_state, dt_limit=cfg.ssm_dt_limit,
-                norm_eps=cfg.norm_eps)
+            if inplace:
+                # the stacked state is read and written once, in place
+                y, conv2, state["ssm"] = ssm_mod.mamba2_decode_stacked(
+                    lp["ssm"], h[:, 0], conv, state["ssm"], k, active,
+                    **ssm_kw)
+            else:
+                st = jax.lax.dynamic_index_in_dim(state["ssm"], k, 0, False)
+                y, conv2, st2 = ssm_mod.mamba2_decode(lp["ssm"], h[:, 0],
+                                                      conv, st, **ssm_kw)
+                state["ssm"] = jax.lax.dynamic_update_index_in_dim(
+                    state["ssm"], keep(st2, st), k, 0)
             state["conv"] = jax.lax.dynamic_update_index_in_dim(
                 state["conv"], keep(conv2, conv), k, 0)
-            state["ssm"] = jax.lax.dynamic_update_index_in_dim(
-                state["ssm"], keep(st2, st), k, 0)
             return y[:, None], state
         q, kk, v = _pattern_qkv(cfg, lp["attn"], h, pos[:, None])
         for name, new in (("k", kk), ("v", v)):

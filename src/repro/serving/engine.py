@@ -315,7 +315,8 @@ class EngineShard:
         with self._runners_lock:
             runners = list(self._runners.values())
         agg = {"lanes": 0, "active": 0, "inserts": 0, "spills": 0,
-               "expiries": 0, "kv_tokens_read": 0, "lane_state_bytes": {}}
+               "expiries": 0, "kv_tokens_read": 0, "ssm_inplace_waves": 0,
+               "lane_state_bytes": {}}
         for r in runners:
             for k, v in r.slot_stats().items():
                 if k == "lane_state_bytes":

@@ -761,12 +761,14 @@ def _lane_map(fn, *trees):
             for name in trees[0]}
 
 
-def _build_zoo_stream_fns(cfg, max_len: int, probed: bool):
+def _build_zoo_stream_fns(cfg, max_len: int, probed: bool, traced: dict):
     """The session programs of a layer-pattern hybrid, lanes of
     ``max_len`` positions: prefill of a history, one decode step of a
     batch of sessions, and the decode lanes' insert, extract and fused
     generate. ``probed``: the prefill and generate also return the
-    ``LogitProbe`` outputs."""
+    ``LogitProbe`` outputs. Tracing the generate sets
+    ``traced["ssm_inplace"]``: whether it updates the SSM state in
+    place."""
     from repro.models import transformer as tfm
 
     V = cfg.vocab
@@ -802,6 +804,7 @@ def _build_zoo_stream_fns(cfg, max_len: int, probed: bool):
 
     def slots_generate(params, tokens, slot_cache, step_mask, ids, xi, scale,
                        active, gamma):
+        traced["ssm_inplace"] = tfm.ssm_decode_inplace(cfg)
         logits, slot_cache = tfm.lm_decode_step(cfg, params, tokens,
                                                 slot_cache, active=step_mask)
         tok, p, mx, lse = _zoo_answer(logits, V, xi, scale, active, gamma)
@@ -887,9 +890,11 @@ class ZooForecaster:
             return tok.astype(jnp.int32), surprisal
 
         self._fwd = jax.jit(_fwd)
+        self._traced: dict = {}
         if self.streaming:
             self._fns = _build_zoo_stream_fns(self.cfg, self.window,
-                                              self.probe is not None)
+                                              self.probe is not None,
+                                              self._traced)
             self._ids = jnp.asarray(
                 self.probe.ids if self.probe is not None else (), jnp.int32)
 
@@ -907,6 +912,13 @@ class ZooForecaster:
     @property
     def decode_width(self) -> int:
         return 1                 # one generate steps every lane
+
+    @property
+    def ssm_inplace(self) -> bool:
+        """Whether the compiled generate updates each Mamba2 layer's SSM
+        state in place in the stacked lanes (the Pallas kernel, on a
+        TPU); False before it is compiled and off a TPU."""
+        return self._traced.get("ssm_inplace", False)
 
     @property
     def vocab(self) -> int:

@@ -582,6 +582,8 @@ class RecurrentSessionRunner:
                                  if hasattr(fc, "lane_state_bytes")
                                  else {"carry": self._nbytes})
         self.kv_tokens_read = 0
+        # generate waves whose program updated the SSM state in place
+        self.ssm_inplace_waves = 0
         window = getattr(fc, "window", None)
         if window and getattr(fc, "feature_dim", 0):
             import numpy as np
@@ -870,6 +872,7 @@ class RecurrentSessionRunner:
         lengths = getattr(self._slots, "lengths", None)
         if lengths is not None:
             self.kv_tokens_read += int(lengths[lanes].sum())
+        self.ssm_inplace_waves += bool(getattr(fc, "ssm_inplace", False))
         for row, idx in enumerate(chunk):
             results[idx] = (float(ys[lanes[row]]), float(ps[lanes[row]]))
 
@@ -925,6 +928,7 @@ class RecurrentSessionRunner:
                     "spills": self.slot_spills,
                     "expiries": self.slot_expiries,
                     "kv_tokens_read": self.kv_tokens_read,
+                    "ssm_inplace_waves": self.ssm_inplace_waves,
                     "lane_state_bytes": dict(self.lane_state_bytes)}
 
     def _run_waves(self, fc, items, waves, version, results) -> None:

@@ -68,17 +68,19 @@ def _sessions(cfg, lengths, steps, seed):
     return hist, {c: rng.integers(0, cfg.vocab, steps) for c in hist}
 
 
-def test_sessions_through_spilling_lanes_match_reference(model):
+def _spill_sessions_against_reference(cfg, params):
     """Three sessions over two lanes: every step evicts a lane, whose
     cache spills to host and comes back on the session's next step.
     The logits of every history position (prefill) and of every step
-    match the reference's full forward over history and steps."""
-    cfg, params = model
+    match the reference's full forward over history and steps. Returns
+    the runner and the number of generate waves."""
     hist, steps = _sessions(cfg, (10, 17, 5), 6, seed=2)
     got = {c: [] for c in hist}
+    waves = 0
     runner = None
 
     def sink(kind, key, out):
+        nonlocal waves
         logits = np.asarray(out[0])
         if kind == "prefill":
             (c,) = [c for c, h in hist.items()
@@ -86,6 +88,7 @@ def test_sessions_through_spilling_lanes_match_reference(model):
             got[c].extend(logits)
         else:
             # inside the runner's generate: its lane map is this wave's
+            waves += 1
             who = {lane: c for c, lane in runner._lanes.items()}
             for lane in key:
                 got[who[lane]].append(logits[lane])
@@ -104,6 +107,30 @@ def test_sessions_through_spilling_lanes_match_reference(model):
                                       np.concatenate([hist[c], steps[c]])))
         assert len(got[c]) == len(want)
         np.testing.assert_allclose(np.stack(got[c]), want, rtol=0, atol=ATOL)
+    return runner, waves
+
+
+def test_sessions_through_spilling_lanes_match_reference(model):
+    """Off a TPU the generate slices each Mamba2 layer's SSM state out
+    of the stacked lanes and writes it back (XLA)."""
+    runner, waves = _spill_sessions_against_reference(*model)
+    assert waves > 0
+    assert runner.forecaster.ssm_inplace is False
+    assert runner.slot_stats()["ssm_inplace_waves"] == 0
+
+
+def test_sessions_through_spilling_lanes_update_ssm_state_in_place(
+        model, monkeypatch):
+    """The generate that a TPU compiles, here with its Pallas kernel in
+    interpret mode: each Mamba2 layer's SSM state is updated in place in
+    the stacked lanes (``ssd_decode_inplace``), and every wave counts."""
+    from repro.kernels.ssd import ops as ssd_ops
+
+    monkeypatch.setattr(ssd_ops, "decode_inplace", lambda: True)
+    runner, waves = _spill_sessions_against_reference(*model)
+    assert runner.forecaster.ssm_inplace is True
+    assert waves > 0 and runner.ssm_inplace_waves == waves
+    assert runner.slot_stats()["ssm_inplace_waves"] == waves
 
 
 def test_sessions_of_different_lengths_in_one_generate(model):

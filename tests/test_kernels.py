@@ -365,3 +365,46 @@ def test_ssd_decode_matches_scan_tail():
         ys.append(y)
     y_seq = jnp.stack(ys, 1)
     np.testing.assert_allclose(y_seq, y_scan, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("stepped", [[1, 1, 1, 1], [0, 0, 0, 0],
+                                     [1, 0, 0, 1]])
+def test_ssd_decode_inplace_matches_decode_step(layer, stepped):
+    """``ssd_decode_inplace`` (interpret mode) on layer ``layer`` of a
+    stacked [L, B, H, P, N] state against ``ssd_decode_step`` on that
+    layer: the stepped lanes' state and y agree to float32 rounding,
+    the other lanes and every other layer are left bit for bit.
+
+    Float32 rounding: the kernel sums y = C . state over N in another
+    order, and the state's two terms may meet in one fused
+    multiply-add on one side only, so an entry that cancels to near 0
+    carries an error of a few ulps of the terms, not of itself: rtol
+    1e-6 plus 1e-6 of the largest magnitude."""
+    from repro.kernels.ssd.ops import ssd_decode_inplace
+    from repro.models.ssm import ssd_decode_step
+
+    L, B, H, P, N = 3, 4, 16, 8, 128
+    states = jnp.asarray(RNG.standard_normal((L, B, H, P, N)), jnp.float32)
+    xd = jnp.asarray(0.3 * RNG.standard_normal((B, H, P)), jnp.float32)
+    a = -jnp.asarray(RNG.uniform(0.01, 0.5, (B, H)), jnp.float32)
+    B_ = jnp.asarray(RNG.standard_normal((B, N)), jnp.float32)
+    C_ = jnp.asarray(RNG.standard_normal((B, N)), jnp.float32)
+    mask = np.asarray(stepped, bool)
+    before = np.asarray(states)
+
+    y, got = jax.jit(ssd_decode_inplace, donate_argnums=0)(
+        states, jnp.int32(layer), jnp.asarray(mask), a, xd, B_, C_)
+    y, got = np.asarray(y), np.asarray(got)
+    y_want, s_want = (np.asarray(v) for v in ssd_decode_step(
+        jnp.asarray(before[layer]), xd, a, B_, C_))
+
+    def close(have, want):
+        np.testing.assert_allclose(have[mask], want[mask], rtol=1e-6,
+                                   atol=1e-6 * np.abs(want).max())
+
+    close(got[layer], s_want)
+    close(y, y_want)
+    np.testing.assert_array_equal(got[layer][~mask], before[layer][~mask])
+    others = [k for k in range(L) if k != layer]
+    np.testing.assert_array_equal(got[others], before[others])

@@ -1,8 +1,8 @@
-"""The paper LSTM's kernel programs compile for a TPU v5e that is
-described, not attached: the Pallas LSTM cell at its serving and
-training batch sizes, the vmapped training round under
-``value_and_grad``, and the decode-slot ``generate``. Each compiled
-program must contain the kernel (``tpu_custom_call``).
+"""The kernel programs compile for a TPU v5e that is described, not
+attached: the paper LSTM's Pallas cell at its serving and training
+batch sizes, the vmapped training round under ``value_and_grad``, the
+decode-slot ``generate``, and the zoo hybrid's in-place SSM update.
+Each compiled program must contain the kernel (``tpu_custom_call``).
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and
@@ -119,3 +119,32 @@ def test_slots_generate_compiles(one_chip, as_tpu):
         _spec((S,), one_chip, np.bool_), scalar, scalar,
         _spec((), one_chip, np.bool_), gamma=5.0, width=8).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_decode_inplace_compiles_in_place(one_chip, as_tpu):
+    """The hybrid's in-place SSM update at granite-4.0-h-micro's widths
+    (36 Mamba2 layers x 64 lanes x 64 heads x 64 x 128, float32, 4.8 GB)
+    in the layer loop: the kernel is there, and the donated stacked
+    state is aliased through it with no copy (temporaries hold no
+    layer's 134 MB state)."""
+    from repro.kernels.ssd.ops import ssd_decode_inplace
+
+    L, B, H, P, N = 36, 64, 64, 64, 128
+
+    def layers(states, mask, a, xd, B_, C_):
+        def body(k, carry):
+            states, acc = carry
+            y, states = ssd_decode_inplace(states, k, mask, a, xd, B_, C_)
+            return states, acc + y
+        return jax.lax.fori_loop(
+            0, L, body, (states, jnp.zeros((B, H, P), xd.dtype)))
+
+    compiled = jax.jit(layers, donate_argnums=0).lower(
+        _spec((L, B, H, P, N), one_chip), _spec((B,), one_chip, np.bool_),
+        _spec((B, H), one_chip), _spec((B, H, P), one_chip, jnp.bfloat16),
+        _spec((B, N), one_chip, jnp.bfloat16),
+        _spec((B, N), one_chip, jnp.bfloat16)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == L * B * H * P * N * 4
+    assert mem.temp_size_in_bytes < H * P * N * 4
