@@ -1,19 +1,16 @@
 """Serving throughput/latency across micro-batcher settings (ISSUE 1
 acceptance: the dynamic batcher must sustain >= 5x the throughput of
 batch-size-1 serving on the reduced paper LSTM config), plus the
-multi-session DECODE phase (ISSUE 5 acceptance: the batched decode path
-must sustain >= 2x the streaming-step throughput of the per-session
-dispatch loop at >= 8 concurrent sessions — hard-asserted under
-``--smoke``), plus the SLOTS phase (ISSUE 8 acceptance: device-resident
-decode slots must sustain >= 1.5x the gather/scatter steady-state step
-throughput at >= 32 resident sessions, with dispatch counting proving
-zero host gather/scatter — both hard-asserted under ``--smoke``).
+streaming DECODE phase through the engine's step flush, plus the SLOTS
+phase (device-resident decode slots at >= 32 resident sessions, with
+dispatch counting proving one fused generate per flush and nothing
+per-session — hard-asserted under ``--smoke``). Speed is judged on the
+chip (``bench/``); these rows are CPU records.
 
 Rows: ``serve/<config>,us_per_request,rps=..;p95_ms=..;occ=..``, a
 ``serve/speedup_vs_batch1`` row with the headline multiple,
-``serve/decode_*`` rows for the streaming phase with
-``serve/decode_speedup_vs_loop``, and ``serve/slots_*`` rows with
-``serve/slots_speedup_vs_gather`` for the slot-resident phase.
+``serve/decode_engine`` for the streaming phase, and
+``serve/slots_resident`` for the slot-resident phase.
 """
 
 from __future__ import annotations
@@ -88,51 +85,15 @@ def main(n_requests: int = 512, smoke: bool = False) -> None:
         f"{speedup:.1f}x{' (>=5x OK)' if speedup >= 5.0 else ' (BELOW 5x)'}")
 
     # -- decode phase: multi-session streaming steps -----------------------
-    # N resident sessions each advance one observation per tick. The
-    # baseline dispatches one fused step per session per tick (the
-    # pre-batched hot path); the batched decode path gathers all N
-    # carries and flushes each tick as ceil(N/decode_width) fused
-    # dispatches. Identical math, bitwise-equal outputs (tested in
-    # tests/) — this phase measures only the dispatch amortization.
+    # N sessions each advance one observation per tick, end to end
+    # through the engine's step flush grouping: every tick is submitted
+    # without waiting (the flush waves keep one client's steps ordered
+    # even when several ticks share a flush), so this measures pipelined
+    # streaming throughput, not max_wait
     n_sessions = 8 if smoke else 64
     n_ticks = 25 if smoke else 100
     xs = rng.standard_normal(
         (n_ticks, n_sessions, 5)).astype(np.float32) * 0.02
-    fc.warm_decode()
-
-    # num_slots=0 pins both baselines to the pre-slots paths (per-session
-    # dispatch loop, then cache gather -> fused step -> scatter) so the
-    # decode rows stay comparable across the bench trajectory
-    def _loop_phase():
-        runner = RecurrentSessionRunner(
-            fc, SessionCache(max_sessions=n_sessions), num_slots=0)
-        t0 = time.perf_counter()
-        for t in range(n_ticks):
-            for s in range(n_sessions):
-                runner.step(f"s{s}", xs[t, s])
-        return n_ticks * n_sessions / (time.perf_counter() - t0)
-
-    def _batched_phase():
-        runner = RecurrentSessionRunner(
-            fc, SessionCache(max_sessions=n_sessions), num_slots=0)
-        t0 = time.perf_counter()
-        for t in range(n_ticks):
-            runner.step_many([(f"s{s}", xs[t, s], None)
-                              for s in range(n_sessions)])
-        return n_ticks * n_sessions / (time.perf_counter() - t0)
-
-    loop_sps = _loop_phase()
-    batched_sps = _batched_phase()
-    row("serve/decode_per_session_loop", 1e6 / max(loop_sps, 1e-9),
-        f"steps_per_s={loop_sps:.0f};sessions={n_sessions}")
-    row("serve/decode_batched", 1e6 / max(batched_sps, 1e-9),
-        f"steps_per_s={batched_sps:.0f};sessions={n_sessions};"
-        f"width={fc.decode_width}")
-
-    # and end-to-end through the engine's step flush grouping: every
-    # tick is submitted without waiting (the flush waves keep one
-    # client's steps ordered even when several ticks share a flush), so
-    # this measures pipelined streaming throughput, not max_wait
     bcfg = next(c for n, c in _configs(cfg.window) if n == "micro64_w5ms")
     with ServingEngine(reg, bcfg, telemetry=Telemetry()) as eng:
         eng.warmup("m", lengths=(cfg.window,))
@@ -149,83 +110,43 @@ def main(n_requests: int = 512, smoke: bool = False) -> None:
         f"mean_step_batch={snap['mean_step_batch']:.1f};"
         f"step_p95_ms={snap['step_p95_ms']:.2f}")
 
-    decode_speedup = batched_sps / max(loop_sps, 1e-9)
-    ok = decode_speedup >= 2.0
-    row("serve/decode_speedup_vs_loop", 0.0,
-        f"{decode_speedup:.1f}x at {n_sessions} sessions"
-        f"{' (>=2x OK)' if ok else ' (BELOW 2x)'}")
-    if smoke:
-        assert ok, (
-            f"batched decode {decode_speedup:.2f}x at {n_sessions} "
-            f"sessions — the >=2x acceptance bar failed")
-
-    # -- slots phase: device-resident lanes vs gather/scatter --------------
+    # -- slots phase: device-resident lanes --------------------------------
     # Steady state: every session already occupies a device lane, so a
-    # flush is ONE fused slots_generate dispatch — no per-tick carry
-    # gather from the cache, no scatter back. The gather/scatter runner
-    # (num_slots=0) pays the host round-trip every tick. Same math,
-    # bitwise-equal outputs (tested in tests/); dispatch counting proves
-    # the zero-gather/scatter claim rather than asserting it by eye.
+    # flush is ONE fused slots_generate dispatch — no per-session
+    # dispatch, no insert. Dispatch counting proves it rather than
+    # asserting it by eye.
     from repro.kernels import dispatch
 
     n_slot_sessions = 32 if smoke else 64     # acceptance floor is 32
     n_slot_ticks = 25 if smoke else 100
     sxs = rng.standard_normal(
         (n_slot_ticks + 1, n_slot_sessions, 5)).astype(np.float32) * 0.02
-
-    def _gather_phase():
-        runner = RecurrentSessionRunner(
-            fc, SessionCache(max_sessions=n_slot_sessions), num_slots=0)
-        runner.step_many([(f"s{s}", sxs[0, s], None)
-                          for s in range(n_slot_sessions)])   # warm
+    runner = RecurrentSessionRunner(
+        fc, SessionCache(max_sessions=n_slot_sessions),
+        num_slots=n_slot_sessions)
+    # first tick makes every session lane-resident (prefill+insert)
+    runner.step_many([(f"s{s}", sxs[0, s], None)
+                      for s in range(n_slot_sessions)])
+    with dispatch.counting() as counts:
         t0 = time.perf_counter()
         for t in range(1, n_slot_ticks + 1):
             runner.step_many([(f"s{s}", sxs[t, s], None)
                               for s in range(n_slot_sessions)])
-        return n_slot_ticks * n_slot_sessions / (time.perf_counter() - t0)
-
-    def _slots_phase():
-        runner = RecurrentSessionRunner(
-            fc, SessionCache(max_sessions=n_slot_sessions),
-            num_slots=n_slot_sessions)
-        # first tick makes every session lane-resident (prefill+insert)
-        runner.step_many([(f"s{s}", sxs[0, s], None)
-                          for s in range(n_slot_sessions)])
-        with dispatch.counting() as counts:
-            t0 = time.perf_counter()
-            for t in range(1, n_slot_ticks + 1):
-                runner.step_many([(f"s{s}", sxs[t, s], None)
-                                  for s in range(n_slot_sessions)])
-            sps = n_slot_ticks * n_slot_sessions / (time.perf_counter() - t0)
-        return sps, counts
-
-    gather_sps = _gather_phase()
-    slots_sps, counts = _slots_phase()
+        slots_sps = n_slot_ticks * n_slot_sessions / (
+            time.perf_counter() - t0)
     clean = (counts["slots_generate"] == n_slot_ticks
-             and counts["decode_many"] == 0 and counts["decode_step"] == 0
+             and counts["decode_step"] == 0
              and counts["slots_insert"] == 0
              and counts["decode_replay"] == 0)
-    row("serve/slots_gather_scatter", 1e6 / max(gather_sps, 1e-9),
-        f"steps_per_s={gather_sps:.0f};sessions={n_slot_sessions}")
     row("serve/slots_resident", 1e6 / max(slots_sps, 1e-9),
         f"steps_per_s={slots_sps:.0f};sessions={n_slot_sessions};"
         f"generate_dispatches={counts['slots_generate']};"
-        f"gather_scatter_dispatches="
-        f"{counts['decode_many'] + counts['decode_step']}")
-    slots_speedup = slots_sps / max(gather_sps, 1e-9)
-    sok = slots_speedup >= 1.5
-    row("serve/slots_speedup_vs_gather", 0.0,
-        f"{slots_speedup:.1f}x at {n_slot_sessions} resident sessions"
-        f"{' (>=1.5x OK)' if sok else ' (BELOW 1.5x)'}"
+        f"per_session_dispatches={counts['decode_step']}"
         f"{';steady_state_clean' if clean else ';DISPATCH LEAK'}")
     if smoke:
         assert clean, (
-            f"slots steady state leaked host gather/scatter dispatches: "
+            f"slots steady state leaked per-session dispatches: "
             f"{dict(counts)} over {n_slot_ticks} flushes")
-        assert sok, (
-            f"slot-resident decode {slots_speedup:.2f}x at "
-            f"{n_slot_sessions} sessions — the >=1.5x acceptance bar "
-            f"failed")
 
 
 if __name__ == "__main__":
@@ -233,7 +154,7 @@ if __name__ == "__main__":
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced workload + hard decode/slots asserts")
+                    help="reduced workload + the slots dispatch assert")
     ap.add_argument("--requests", type=int, default=512)
     args = ap.parse_args()
     main(n_requests=args.requests, smoke=args.smoke)

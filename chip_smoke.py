@@ -187,6 +187,7 @@ def serve_phase(ckpt: str, seed: int, n_clients: int, decode_slots: int,
     from repro.kernels import dispatch
     from repro.launch import serve
     from repro.serving import BatcherConfig, ModelRegistry, ServingEngine
+    from repro.serving.forecaster import _donate_default
 
     serve.main(["--checkpoint", ckpt, "--model", KEY, "--sessions",
                 "--requests", str(requests), "--seed", str(seed)])
@@ -236,12 +237,12 @@ def serve_phase(ckpt: str, seed: int, n_clients: int, decode_slots: int,
         step_err = float(np.max(np.abs(stepped - want)))
         print(f"serve: {n_clients} clients x {T} steps over "
               f"{runner.num_slots} lanes: {slots['spills']} spills, "
-              f"{slots['inserts']} inserts, carry donation "
-              f"{'on' if runner.donate_carries else 'off'}")
+              f"{slots['inserts']} inserts, lane donation "
+              f"{'on' if _donate_default() else 'off'}")
         check(slots["spills"] > 0 and slots["inserts"] > n_clients,
               f"lanes never spilled and reloaded: {slots}")
-        check(runner.donate_carries or jax.default_backend() == "cpu",
-              "carry donation is off on an accelerator")
+        check(_donate_default() or jax.default_backend() == "cpu",
+              "lane donation is off on an accelerator")
         generate = fc._fns["slots_generate_donate"].lower(
             fc.params, np.zeros((runner.num_slots, F), np.float32),
             runner._slots.carry, np.zeros((runner.num_slots,), bool),

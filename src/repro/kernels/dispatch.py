@@ -227,12 +227,11 @@ class counting:
         with dispatch.counting() as counts:
             engine.submit_step(...)          # ... flush ...
         assert counts["slots_generate"] == 1   # one fused dispatch
-        assert counts["decode_many"] == 0      # no host gather/scatter
+        assert counts["decode_step"] == 0      # nothing per-session
 
     This is how tier-1 proves the steady-state decode contract: each
     step flush is exactly one ``slots_generate`` dispatch over the
-    device-resident slot state (``decode_many`` — the cache
-    gather/scatter path — and per-session ``decode_step`` both stay
+    device-resident slot state (per-session ``decode_step`` stays
     zero; ``slots_insert`` fires only when a session enters a lane).
     Collectors nest (each sees every dispatch while installed)."""
 

@@ -320,8 +320,7 @@ def main(argv: list[str] | None = None) -> None:
             # engine-resident sessions over the slotted decode path:
             # carries live in device decode lanes between ticks, so each
             # tick's steps flush as ONE fused slots_generate dispatch
-            # per shard instead of one jit dispatch per client (or a
-            # per-tick host gather/scatter through the cache)
+            # per shard instead of one jit dispatch per client
             streams = _traffic_datasets(min(args.clients, 8), fc.window,
                                         args.seed + 1)
             t0s = time.time()

@@ -143,41 +143,6 @@ def init_rnn_carry(params: PyTree, batch: int, dtype=jnp.float32):
         for lp in params["lstm"])
 
 
-def stack_rnn_carries(carries, pad_to: int | None = None):
-    """Stack per-session carries (each ``init_rnn_carry(params, 1)``
-    shaped) into one batched carry: N x ([1, H], [1, H]) per layer ->
-    ([N, H], [N, H]) per layer. ``pad_to`` right-pads the batch dim with
-    zero rows (the decode lane's fixed width) in the same concatenate —
-    one op per tensor, and the stacked buffer is freshly allocated, so
-    the caller owns it (donation-safe)."""
-    n = len(carries)
-    pad = (pad_to - n) if pad_to is not None else 0
-    if pad < 0:
-        raise ValueError(f"cannot pad {n} carries to width {pad_to}")
-    out = []
-    for layer in range(len(carries[0])):
-        parts_h = [c[layer][0] for c in carries]
-        parts_c = [c[layer][1] for c in carries]
-        if pad:
-            z = jnp.zeros((pad,) + tuple(parts_h[0].shape[1:]),
-                          parts_h[0].dtype)
-            parts_h = parts_h + [z]
-            parts_c = parts_c + [z]
-        out.append((jnp.concatenate(parts_h, axis=0),
-                    jnp.concatenate(parts_c, axis=0)))
-    return tuple(out)
-
-
-def split_rnn_carry(carry, n: int | None = None):
-    """Inverse of ``stack_rnn_carries``: a batched carry -> list of
-    batch-1 per-session carries (first ``n`` rows; padding rows beyond
-    ``n`` are dropped)."""
-    batch = carry[0][0].shape[0]
-    n = batch if n is None else n
-    return [tuple((h[i:i + 1], c[i:i + 1]) for h, c in carry)
-            for i in range(n)]
-
-
 def rnn_step(params: PyTree, x_t, carries, cfg: RNNConfig):
     """One time step: x_t [B, input_dim], carries from ``init_rnn_carry``.
 

@@ -105,7 +105,6 @@ SPANS = {
     "repro.readback":
         "the host waiting for and copying a forecast and alert back",
     "repro.decode_step": "one single-chunk decode step call",
-    "repro.decode_many": "one batched gather/scatter decode call",
     "repro.decode_replay": "one full-window replay (prefill) call",
     "repro.zoo.prefill":
         "a token session primed from its history: the prefill program's "

@@ -24,15 +24,14 @@ Layout (DESIGN: one concern per module):
                     carry to the host ``SessionCache``, bitwise-equal
                     on reload; TTL expires lanes too);
                     ``RecurrentSessionRunner.step_many`` is "ensure
-                    resident -> generate -> read requested rows"
-                    (``num_slots=0`` restores the gather/scatter path);
+                    resident -> generate -> read requested rows";
                     ``ShardedSessionCache`` shards by client id;
 - ``forecaster.py`` the ``Forecaster``/``StreamingForecaster``
                     protocols — one ``predict(window) -> (forecast,
                     p_extreme)`` interface over the paper LSTM and every
                     zoo arch, with the EVT tail alert head;
                     ``DecodeSlots`` + prefill/insert/generate, the
-                    device-resident decode lifecycle (carries donated in
+                    device-resident decode lifecycle (lane state donated in
                     and out off-CPU);
 - ``ensemble.py``   composable model-set serving: ``EnsembleForecaster``
                     fans one request across N registry members and fuses

@@ -54,16 +54,15 @@ class BatcherConfig:
     pad_batch: bool = True
     # device-resident decode lanes per model runner (rounded up to the
     # forecaster's decode width): streaming sessions stay resident on
-    # device between steps and a flush is ONE fused generate dispatch.
-    # 0 disables slots and restores the cache gather/scatter decode path
+    # device between steps and a flush is ONE fused generate dispatch
     decode_slots: int = 64
 
     def __post_init__(self):
         if self.max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {self.max_batch}")
-        if self.decode_slots < 0:
+        if self.decode_slots < 1:
             raise ValueError(
-                f"decode_slots must be >= 0, got {self.decode_slots}")
+                f"decode_slots must be >= 1, got {self.decode_slots}")
         if self.pad_batch and self.max_batch & (self.max_batch - 1):
             # a non-pow2 max_batch would make bucket_batch emit a non-pow2
             # clamped shape, breaking the "{pow2 batches} x {length
@@ -109,9 +108,10 @@ class _Request:
 
 class _StepRequest:
     """One streaming step: a single feature vector for a session whose
-    carry lives in the shard's session cache. Grouped per model and
-    flushed as ONE fused decode dispatch (``RecurrentSessionRunner.
-    step_many``), not one dispatch per client."""
+    carry lives in a decode lane of the shard's runner (or its session
+    cache, spilled). Grouped per model and flushed as ONE fused generate
+    (``RecurrentSessionRunner.step_many``), not one dispatch per
+    client."""
 
     __slots__ = ("payload", "history", "future", "t_enq", "client_id",
                  "trace", "t_trace")
@@ -153,19 +153,11 @@ class EngineShard:
 
     def __init__(self, registry, config: BatcherConfig | None = None,
                  telemetry: Telemetry | None = None, shard_id: int = 0,
-                 session_cache=None, tracer=None,
-                 donate_carries: bool | None = None):
+                 session_cache=None, tracer=None):
         self.registry = registry
         self.config = config or BatcherConfig()
         self.telemetry = telemetry if telemetry is not None else Telemetry()
         self.shard_id = shard_id
-        # donate session carries to the fused step? None -> platform
-        # default (on off-CPU, off on CPU). Safe only while the flush
-        # worker is the sole toucher of the session state during
-        # serving; the transport worker passes False because its recv
-        # loop can ``extract``/``restore`` carries concurrently with
-        # flushes
-        self.donate_carries = donate_carries
         # per-request trace spans (repro.obs.Tracer); None -> no tracing
         self.tracer = tracer
         # trace meta is shared by reference (one dict per model, not one
@@ -256,12 +248,8 @@ class EngineShard:
 
                     # provider-backed: the runner re-resolves the
                     # registry key each flush, so weight hot-swaps are
-                    # picked up without rebuilding the runner. Carry
-                    # donation (no-op on CPU) follows the shard knob —
-                    # see __init__
-                    # slot-capable forecasters get decode_slots device
-                    # lanes; others (and decode_slots=0) keep the
-                    # gather/scatter path
+                    # picked up without rebuilding the runner; it holds
+                    # decode_slots device lanes.
                     # ensemble names resolve to the shard's stable
                     # EnsembleForecaster runtime (which re-resolves its
                     # members per call): composite per-member carries
@@ -271,13 +259,9 @@ class EngineShard:
                         provider = lambda: self._ensemble(model_key)  # noqa: E731
                     else:
                         provider = lambda: self.registry.get(model_key)  # noqa: E731
-                    fc = provider()
-                    n_slots = self.config.decode_slots \
-                        if hasattr(fc, "init_slots") else 0
                     runner = RecurrentSessionRunner(
                         provider, cache=cache,
-                        donate_carries=self.donate_carries,
-                        num_slots=n_slots)
+                        num_slots=self.config.decode_slots)
                     self._runners[model_key] = runner
         return runner
 
@@ -683,12 +667,8 @@ class EngineShard:
                     [t] * b, b, t))
                 n += 1
         if streams(fc):
-            # the streaming decode lane: single step, batched flush and
-            # miss-replay programs, plus the runner itself (its ctor
-            # pre-compiles the full-window replay and the lane programs)
-            # — all off the hot path
-            if hasattr(fc, "warm_decode"):
-                n += fc.warm_decode()
+            # the runner: its ctor compiles the full-window replay and
+            # the lane programs off the hot path
             self._step_runner(model_key)
         return n
 
@@ -714,8 +694,8 @@ class EngineShard:
 
     def _flush_steps(self, model_key: str, reqs: list[_StepRequest]) -> None:
         """One batched decode flush: every queued step for ``model_key``
-        becomes one fused dispatch per decode-lane chunk via the
-        session runner's gather/scatter ``step_many``."""
+        becomes one fused generate over the session runner's decode
+        lanes (``step_many``)."""
         tracer = self.tracer
         traced, deferred, fspans = self._trace_gather(tracer, reqs)
         if fspans is not None:
@@ -738,16 +718,14 @@ class EngineShard:
                 fspans.stamp("dispatch")
             now = time.perf_counter()
             version = getattr(fc, "version", None)
-            # lane slots actually dispatched (waves for duplicate clients,
-            # each padded to the decode width) — counted by the runner at
-            # the dispatch decision, not re-derived here
-            padded = getattr(runner, "last_step_slots", len(reqs))
+            # lane slots actually dispatched (a generate over every lane
+            # per wave) — counted by the runner at the dispatch decision
             self.telemetry.record_step_batch([now - r.t_enq for r in reqs],
-                                             n_padded=padded,
+                                             n_padded=runner.last_step_slots,
                                              model=model_key)
             spec = self._ensemble_spec(model_key)
             if spec is not None:
-                # the fuse happened inside the ensemble runtime's step_many;
+                # the fuse happened inside the ensemble runtime's generate;
                 # surface its alert/anomaly outcome into telemetry and the
                 # flush worker's max_wait multipliers
                 rt = self._ensemble(model_key)

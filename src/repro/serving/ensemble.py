@@ -28,9 +28,8 @@ and the engine tightens the batcher's ``max_wait`` for the ensemble
 and its members (``wait_scale`` < 1 — alerts leave the queue sooner).
 
 Every member runs through the EXISTING fused per-model machinery: an
-ensemble ``predict`` is N per-model ``predict`` dispatches, an
-ensemble ``step_many`` flush is N fused ``decode_many`` dispatches, a
-slotted ensemble tick is N fused ``slots_generate`` dispatches — never
+ensemble ``predict`` is N per-model ``predict`` dispatches and an
+ensemble session tick is N fused ``slots_generate`` dispatches — never
 N×batch singles.
 """
 
@@ -395,25 +394,6 @@ class EnsembleForecaster:
         fused = self.fuse(ys, ps)
         return fused.forecast, fused.p_extreme, new
 
-    def step_many(self, xs, carries, donate: bool | None = None):
-        """Batched streaming step for N sessions: every member steps
-        ALL N sessions through its own fused decode lane (N member
-        dispatches per flush, never N×sessions singles), then the rows
-        fuse."""
-        _, members = self._members()
-        n = len(carries)
-        ys, ps, per_member = [], [], {}
-        for k, m in members:
-            mc = [self._member_carry(c, k, m) for c in carries]
-            y, p, out = m.step_many(xs, mc, donate=donate)
-            ys.append(y)
-            ps.append(p)
-            per_member[k] = out
-        fused = self.fuse(ys, ps)
-        new = [{k: per_member[k][i] for k, _ in members}
-               for i in range(n)]
-        return fused.forecast, fused.p_extreme, new
-
     def replay(self, window, carry=None):
         """Full-window re-prime through every member's own replay (one
         fused dispatch each). Fusion runs with ``update=False`` — a
@@ -497,8 +477,3 @@ class EnsembleForecaster:
         _, members = self._members()
         return sum(m.warm_slots(num_slots) for _, m in members
                    if hasattr(m, "warm_slots"))
-
-    def warm_decode(self) -> int:
-        _, members = self._members()
-        return sum(m.warm_decode() for _, m in members
-                   if hasattr(m, "warm_decode"))

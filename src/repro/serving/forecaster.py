@@ -16,8 +16,8 @@ Two concrete single-model implementations:
   forecast is the next-step normalized close; the extreme probability
   fuses the trained EVL sigmoid head with the EVT tail machinery of
   ``repro.extreme`` (eq. 3 GEV depth-into-tail + eq. 4 exceedance), with
-  the eq. 1 indicator as the discrete alert. Supports O(1) incremental
-  ``step`` with explicit carries for the session cache.
+  the eq. 1 indicator as the discrete alert. Streams sessions through
+  the decode lanes, its carry the per-layer (h, c) state.
 
 - ``ZooForecaster`` — any ``repro.models.model_zoo`` arch serving
   next-token prediction; the "extreme event" is an anomalously
@@ -44,8 +44,7 @@ from repro.extreme.evt import fit_tail, gev_cdf, tail_probability
 from repro.extreme.indicators import indicator_sequence, quantile_thresholds
 from repro.kernels import dispatch
 from repro.models.rnn import (RNNConfig, init_rnn, init_rnn_carry,
-                              rnn_apply_padded, rnn_step, split_rnn_carry,
-                              stack_rnn_carries)
+                              rnn_apply_padded, rnn_step)
 from repro.obs import trace as obs_trace
 
 PyTree = Any
@@ -71,14 +70,19 @@ class Forecaster(Protocol):
 
 @runtime_checkable
 class StreamingForecaster(Forecaster, Protocol):
-    """A ``Forecaster`` that also serves O(1) streaming: explicit
-    carries (opaque pytrees to callers — the LSTM's per-layer (h, c)
-    tuples, a zoo hybrid's decode cache of SSM, conv and key/value
-    state, an ensemble's {member: carry} dict), history replay, and
-    device-resident decode slots. A step's input is one feature vector
-    [F], or one token id where ``feature_dim`` is 0. This is the full
-    contract ``RecurrentSessionRunner`` / ``DecodeSlots`` serving is
-    written against."""
+    """A ``Forecaster`` that also serves O(1) streaming sessions through
+    device-resident decode lanes. Carries are opaque pytrees to callers
+    (the LSTM's per-layer (h, c) tuples, a zoo hybrid's decode cache of
+    SSM, conv and key/value state, an ensemble's {member: carry} dict);
+    a step's input is one feature vector [F], or one token id where
+    ``feature_dim`` is 0. This is the contract ``RecurrentSessionRunner``
+    is written against: a session lives in a lane (``init_slots``), is
+    primed from its history (``prefill``, the same as ``replay``), enters
+    a lane (``insert``), is stepped with every other stepped lane in one
+    program (``generate``), leaves it for the host's spill tier
+    (``extract``, ``release``), and every lane program compiles off the
+    serving path (``warm_slots``). ``step`` is the one-session
+    functional step that lanes are bitwise-equal to."""
 
     @property
     def decode_width(self) -> int: ...
@@ -89,9 +93,21 @@ class StreamingForecaster(Forecaster, Protocol):
 
     def step(self, x_t, carry): ...
 
-    def step_many(self, xs, carries, donate=None): ...
-
     def replay(self, window, carry=None): ...
+
+    def prefill(self, window, carry=None): ...
+
+    def init_slots(self, num_slots: int): ...
+
+    def insert(self, slots, lane: int, carry, donate=None): ...
+
+    def extract(self, slots, lane: int): ...
+
+    def release(self, slots, lane: int) -> None: ...
+
+    def generate(self, slots, x, lanes=None, donate=None): ...
+
+    def warm_slots(self, num_slots: int) -> int: ...
 
 
 def input_spec(fc) -> tuple[tuple[int, ...], Any]:
@@ -166,92 +182,69 @@ def _build_rnn_fns(cfg: RNNConfig):
         return y, _fused_alert(jnp.abs(y), u, xi, scale, active,
                                gamma), carry
 
-    def replay(params, window, carry, xi, scale, active, gamma):
-        # one lax.scan over the SAME fused per-step computation the
-        # session path runs (``step`` above, alert head included), so
-        # a cache-miss replay is ONE dispatch instead of O(window)
-        # host round trips. The scan is fully unrolled with
-        # optimization barriers at each step's boundary: inside a
-        # rolled loop body XLA selects instructions differently (FMA
-        # contraction, fusion shapes) than in the standalone step
-        # program, which breaks the session cache's bitwise
-        # step==replay promise in the low bits — unrolled
-        # barrier-isolated per-step subgraphs reproduce the
-        # standalone step's compilation context exactly (window
-        # lengths are bounded by cfg.window, so the unrolled
-        # programs stay small).
-        def body(c, x_t):
-            x_t, c = jax.lax.optimization_barrier((x_t, c))
-            y, p, c2 = step(params, x_t, c, xi, scale, active, gamma)
-            y, p, c2 = jax.lax.optimization_barrier((y, p, c2))
-            return c2, (y, p, c2)
-
-        carry, (ys, ps, _cs) = jax.lax.scan(
-            body, carry, jnp.swapaxes(window, 0, 1),
-            unroll=window.shape[1])
-        # EVERY per-step output — y, p, and the intermediate carries
-        # — is returned live (callers take [-1] / the final carry):
-        # were any of them dead code, XLA would prune parts of the
-        # earlier iterations and re-fuse what remains differently
-        # from the standalone step program, breaking bitwise parity
-        # (measured: stacking y/p alone is not enough)
-        return ys, ps, _cs, carry
-
     # -- decode lane -----------------------------------------------
-    # Every streaming step — single-session or a batched flush —
-    # executes the SAME barrier-isolated step subgraph at one fixed
-    # batch width. That is what makes batched-step == per-session
-    # step == replay hold BITWISE: XLA compiles the fused step
+    # Every streaming step (a session's replay, a one-session step, a
+    # generate over the lanes) runs the SAME step subgraph at one
+    # fixed batch width, ``lane_step``. That is what makes step ==
+    # replay == generate hold BITWISE: XLA compiles the fused step
     # differently at different batch shapes (measured: batch-N and
     # batch-1 programs disagree in the low bits), but within one
     # program each row's output is a pure function of that row, so
-    # padding rows can never perturb real sessions. The barriers
-    # isolate the width-W step subgraph from the surrounding
-    # pad/gather graph exactly like ``replay``'s per-step barriers
-    # do — all lane programs therefore share one compilation
-    # context for the step math.
+    # padding rows never perturb real sessions. The select that keeps
+    # the carry of the lanes a call does not step is part of the
+    # subgraph in every program: the CPU compiler expands the barriers
+    # before it fuses, and a select fused onto the cell update in one
+    # program but not in another contracts its multiply-adds
+    # differently (measured: one ulp of the cell state). Measured
+    # bitwise on the CPU at widths 2 and 8; at width 1 a replay still
+    # drifts from the step.
 
-    def decode_step(params, x_t, carry, xi, scale, active, gamma,
-                    width):
-        # x_t [b, F], carry [b, H]-stacked, b <= width (static)
-        pad = width - x_t.shape[0]
+    def lane_step(params, x, carry, mask, xi, scale, active, gamma):
+        # x [W, F], carry [W, H]-stacked, mask [W] the lanes stepped
+        x, carry, mask = jax.lax.optimization_barrier((x, carry, mask))
+        y, p, c2 = step(params, x, carry, xi, scale, active, gamma)
+        c2 = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(mask[:, None], new, old), c2, carry)
+        return jax.lax.optimization_barrier((y, p, c2))
+
+    def decode_step(params, x_t, carry, mask, xi, scale, active, gamma):
+        # x_t [b, F], carry [b, H]-stacked, b <= width = len(mask)
+        pad = mask.shape[0] - x_t.shape[0]
         xp = jnp.pad(x_t, ((0, pad), (0, 0)))
         cp = jax.tree_util.tree_map(
             lambda a: jnp.pad(a, ((0, pad), (0, 0))), carry)
-        xp, cp = jax.lax.optimization_barrier((xp, cp))
-        y, p, c2 = step(params, xp, cp, xi, scale, active, gamma)
-        y, p, c2 = jax.lax.optimization_barrier((y, p, c2))
+        y, p, c2 = lane_step(params, xp, cp, mask, xi, scale, active, gamma)
         b = x_t.shape[0]
-        return y[:b], p[:b], jax.tree_util.tree_map(
-            lambda a: a[:b], c2)
+        return y[:b], p[:b], jax.tree_util.tree_map(lambda a: a[:b], c2)
 
-    def decode_many(params, x_t, carries, xi, scale, active, gamma):
-        # x_t [W, F]; carries: tuple of W per-session batch-1
-        # carries (padding slots hold zero carries). Per-session
-        # buffers go in and come out as separate jit args/results,
-        # so a batched flush is ONE dispatch with no eager
-        # gather/scatter ops around it.
-        stacked = stack_rnn_carries(carries)
-        xp, cp = jax.lax.optimization_barrier((x_t, stacked))
-        y, p, c2 = step(params, xp, cp, xi, scale, active, gamma)
-        y, p, c2 = jax.lax.optimization_barrier((y, p, c2))
-        return y, p, tuple(split_rnn_carry(c2))
-
-    def decode_replay(params, window, carry, xi, scale, active,
-                      gamma, width):
-        # window [b, T, F], b <= width: replay at lane width so the
-        # unrolled per-step subgraphs match the decode steps'
-        pad = width - window.shape[0]
+    def decode_replay(params, window, carry, mask, xi, scale, active,
+                      gamma):
+        # window [b, T, F], b <= width = len(mask): one lax.scan over
+        # the lane step, so a cache-miss replay is ONE dispatch instead
+        # of O(window) host round trips. The scan is fully unrolled:
+        # inside a rolled loop body XLA selects instructions
+        # differently (FMA contraction, fusion shapes) than in the
+        # standalone step program (window lengths are bounded by
+        # cfg.window, so the unrolled programs stay small).
+        pad = mask.shape[0] - window.shape[0]
         wp = jnp.pad(window, ((0, pad), (0, 0), (0, 0)))
         cp = jax.tree_util.tree_map(
             lambda a: jnp.pad(a, ((0, pad), (0, 0))), carry)
-        ys, ps, cs, c2 = replay(params, wp, cp, xi, scale, active,
-                                gamma)
+
+        def body(c, x_t):
+            y, p, c2 = lane_step(params, x_t, c, mask, xi, scale, active,
+                                 gamma)
+            return c2, (y, p, c2)
+
+        c2, (ys, ps, cs) = jax.lax.scan(
+            body, cp, jnp.swapaxes(wp, 0, 1), unroll=wp.shape[1])
         b = window.shape[0]
-        # the intermediate carries stay live in the output (sliced,
-        # like every other result) — pruning them re-fuses the
-        # earlier unrolled steps and breaks bitwise parity (see the
-        # measured note in ``replay`` above)
+        # EVERY per-step output, the intermediate carries included,
+        # stays live in the result (sliced, like every other result):
+        # were any of them dead code, XLA would prune parts of the
+        # earlier iterations and re-fuse what remains differently from
+        # the standalone step program, breaking bitwise parity
+        # (measured: stacking y/p alone is not enough)
         return (ys[:, :b], ps[:, :b],
                 jax.tree_util.tree_map(lambda a: a[:, :b], cs),
                 jax.tree_util.tree_map(lambda a: a[:b], c2))
@@ -265,10 +258,7 @@ def _build_rnn_fns(cfg: RNNConfig):
     # copy). ``extract`` is its inverse (spill / migration read).
     # ``generate`` steps ALL lanes in one dispatch: the slot state is
     # walked in static chunks of the decode-lane width, each chunk
-    # running the SAME barrier-isolated step subgraph as
-    # decode_step/decode_many above — one compilation context for the
-    # step math, so a lane's output stays bitwise-equal to the
-    # per-session step/replay path.
+    # running ``lane_step``.
 
     def slots_insert(slot_carry, carry, lane):
         # slot_carry [S, H]-stacked, carry [1, H]-stacked, lane int32
@@ -284,54 +274,29 @@ def _build_rnn_fns(cfg: RNNConfig):
     def slots_generate(params, x, slot_carry, step_mask, xi, scale,
                        active, gamma, width):
         # x [S, F], slot_carry [S, H]-stacked, S a static multiple of
-        # ``width``. step_mask [S] marks the lanes this flush actually
-        # steps: resident lanes that are NOT part of the flush pass
-        # their carry through unchanged (the select happens OUTSIDE
-        # the barrier-isolated step subgraphs, so it cannot perturb
-        # the stepped rows' bits).
+        # ``width``. step_mask [S] marks the lanes this flush steps;
+        # the others pass their carry through unchanged.
         S = x.shape[0]
         ys, ps, cs = [], [], []
         for lo in range(0, S, width):
-            xc = x[lo:lo + width]
-            cc = jax.tree_util.tree_map(lambda a: a[lo:lo + width],
-                                        slot_carry)
-            xc, cc = jax.lax.optimization_barrier((xc, cc))
-            y, p, c2 = step(params, xc, cc, xi, scale, active, gamma)
-            y, p, c2 = jax.lax.optimization_barrier((y, p, c2))
+            y, p, c2 = lane_step(
+                params, x[lo:lo + width],
+                jax.tree_util.tree_map(lambda a: a[lo:lo + width],
+                                       slot_carry),
+                step_mask[lo:lo + width], xi, scale, active, gamma)
             ys.append(y)
             ps.append(p)
             cs.append(c2)
-        y = jnp.concatenate(ys)
-        p = jnp.concatenate(ps)
-        stepped = jax.tree_util.tree_map(
-            lambda *parts: jnp.concatenate(parts, axis=0), *cs)
-        m = step_mask[:, None]
-        new_carry = jax.tree_util.tree_map(
-            lambda old, new: jnp.where(m, new, old), slot_carry, stepped)
-        return y, p, new_carry
+        return jnp.concatenate(ys), jnp.concatenate(ps), \
+            jax.tree_util.tree_map(
+                lambda *parts: jnp.concatenate(parts, axis=0), *cs)
 
     # gamma is static: gev_log_cdf branches on it in Python, and it
     # is a per-deployment constant (one compile per distinct value)
     return {
         "predict": jax.jit(predict, static_argnames=("gamma",)),
-        # NOTE: no standalone (non-lane) step program is exposed — every
-        # streaming step must go through the fixed-width decode lane
-        # below, or the bitwise step==replay==batched-step contract dies
-        "replay": jax.jit(replay, static_argnames=("gamma",)),
-        "decode_step": jax.jit(decode_step,
-                               static_argnames=("gamma", "width")),
-        "decode_many": jax.jit(decode_many,
-                               static_argnames=("gamma",)),
-        # the donating variant: per-session carry buffers handed to
-        # the lane are consumed in place (no copy into the stacked
-        # batch). Only safe when the caller exclusively owns them —
-        # the engine-internal runner does; see ``step_many`` — and
-        # only useful off-CPU (CPU donation is a no-op that warns)
-        "decode_many_donate": jax.jit(decode_many,
-                                      static_argnames=("gamma",),
-                                      donate_argnums=(2,)),
-        "decode_replay": jax.jit(decode_replay,
-                                 static_argnames=("gamma", "width")),
+        "decode_step": jax.jit(decode_step, static_argnames=("gamma",)),
+        "decode_replay": jax.jit(decode_replay, static_argnames=("gamma",)),
         "slots_insert": jax.jit(slots_insert),
         # in-place lane write: the slot state is donated back to
         # itself, so an insert never copies the other lanes
@@ -406,7 +371,7 @@ class LSTMForecaster:
     published_at: float | None = None
     # decode-lane width: EVERY streaming step/replay runs the fused step
     # at this fixed batch width (padded; larger batches chunk), which is
-    # what keeps step == replay == batched-step bitwise-equal — XLA
+    # what keeps step == replay == generate bitwise-equal — XLA
     # compiles different batch shapes differently, one shared width
     # side-steps that. 8 = one TPU sublane tile; also the Pallas
     # kernel's block_b.
@@ -418,9 +383,6 @@ class LSTMForecaster:
             raise ValueError(
                 f"decode_width must be >= 1, got {self.decode_width}")
         self._fns = _compiled_rnn(self.cfg)
-        # one zero per-session carry, shared by every padding slot of a
-        # partial batched flush (never donated — see step_many)
-        self._zero_session = init_rnn_carry(self.params, 1)
 
     # -- batched serving ---------------------------------------------------
     @property
@@ -482,8 +444,10 @@ class LSTMForecaster:
     def step(self, x_t, carry):
         """One O(1) streaming step: x_t [B, F]. Returns
         (forecast [B], p_extreme [B], new_carry) — one fused dispatch
-        through the decode lane (the step runs padded at
-        ``decode_width``; batches beyond the width chunk)."""
+        of the lane step (padded to ``decode_width``; batches beyond
+        the width chunk), bitwise-equal to ``generate`` stepping the
+        same carries in lanes. The one-session functional API: no
+        serving path calls it (sessions step through the lanes)."""
         x_t = jnp.asarray(x_t, jnp.float32)
         B = x_t.shape[0]
         W = self.decode_width
@@ -500,58 +464,15 @@ class LSTMForecaster:
         dispatch.record("decode_step", batch=W, hidden=self.cfg.hidden,
                         kernel_op="lstm_cell")
         with obs_trace.span("repro.decode_step"):
-            y, p, carry = self._fns["decode_step"](self.params, x_t, carry,
-                                                   *self._tail_args(),
-                                                   gamma=float(self.gamma),
-                                                   width=W)
+            y, p, carry = self._fns["decode_step"](
+                self.params, x_t, carry, self._lane_mask(B),
+                *self._tail_args(), gamma=float(self.gamma))
         return np.asarray(y), np.asarray(p), carry
 
-    def step_many(self, xs, carries, donate: bool | None = None):
-        """Batched streaming step for N independent sessions: xs [N, F],
-        ``carries`` a list of N batch-1 carries (one per session, as the
-        session cache holds them). Returns (forecast [N], p_extreme [N],
-        new_carries list) in ceil(N / decode_width) fused dispatches —
-        per-session buffers travel as jit arguments/results, so the
-        gather/scatter around the lane costs no extra dispatches.
-
-        ``donate=True`` additionally donates the input carry buffers to
-        the lane (they are consumed — no copy into the stacked batch).
-        The default (``None``) resolves to True off-CPU and False on CPU
-        (XLA:CPU implements donation as a warn + copy). Donation is only
-        safe when the caller exclusively owns every carry: the
-        engine-internal runner does (one worker thread, cache exported
-        only after drain); carries that a concurrent reader could still
-        hand out (live-membership migration) must pass ``donate=False``
-        explicitly — the transport workers do."""
-        xs = np.asarray(xs, np.float32)
-        N = len(carries)
-        W = self.decode_width
-        donate = _donate_default() if donate is None \
-            else (donate and jax.default_backend() != "cpu")
-        fn = self._fns["decode_many_donate" if donate else "decode_many"]
-        ys, ps, out = [], [], []
-        for lo in range(0, N, W):
-            chunk = list(carries[lo:lo + W])
-            n = len(chunk)
-            if n < W:
-                # padding slots: the shared zero carry (fresh buffers
-                # when donating — a buffer may be donated only once)
-                pad = [init_rnn_carry(self.params, 1) for _ in
-                       range(W - n)] if donate \
-                    else [self._zero_session] * (W - n)
-                chunk.extend(pad)
-            x = np.zeros((W, xs.shape[1]), np.float32)
-            x[:n] = xs[lo:lo + n]
-            dispatch.record("decode_many", batch=W, hidden=self.cfg.hidden,
-                            kernel_op="lstm_cell")
-            with obs_trace.span("repro.decode_many"):
-                y, p, sessions = fn(self.params, x, tuple(chunk),
-                                    *self._tail_args(),
-                                    gamma=float(self.gamma))
-            ys.append(np.asarray(y)[:n])
-            ps.append(np.asarray(p)[:n])
-            out.extend(sessions[:n])
-        return np.concatenate(ys), np.concatenate(ps), out
+    def _lane_mask(self, b: int) -> np.ndarray:
+        """The first ``b`` of ``decode_width`` lanes: the rows a padded
+        step or replay advances."""
+        return np.arange(self.decode_width) < b
 
     def replay(self, window, carry=None):
         """Full-window recompute through the *same* per-step math the
@@ -582,8 +503,8 @@ class LSTMForecaster:
                         kernel_op="lstm_cell")
         with obs_trace.span("repro.decode_replay"):
             ys, ps, _, carry = self._fns["decode_replay"](
-                self.params, window, carry, *self._tail_args(),
-                gamma=float(self.gamma), width=W)
+                self.params, window, carry, self._lane_mask(B),
+                *self._tail_args(), gamma=float(self.gamma))
         return np.asarray(ys[-1]), np.asarray(ps[-1]), carry
 
     # -- device-resident decode slots (prefill / insert / generate) --------
@@ -687,25 +608,6 @@ class LSTMForecaster:
         self.generate(slots, x, lanes=[0], donate=False)
         self.generate(slots, x, lanes=[0], donate=True)
         return 5
-
-    def warm_decode(self) -> int:
-        """Compile the decode-lane programs (single step, batched flush
-        in both its plain and donating variants, full-window replay) off
-        the serving path. Returns #programs the streaming hot path can
-        hit."""
-        F = self.feature_dim
-        W = self.decode_width
-        self.step(np.zeros((1, F), np.float32), self.init_carry(1))
-        self.step_many(np.zeros((W, F), np.float32),
-                       [self.init_carry(1) for _ in range(W)])
-        # the donating variant is what the engine's runner dispatches
-        # off-CPU — it must be compiled here too, not on the first
-        # flush (on CPU this resolves to the plain program: cache hit)
-        self.step_many(np.zeros((W, F), np.float32),
-                       [self.init_carry(1) for _ in range(W)],
-                       donate=True)
-        self.replay(np.zeros((1, self.window, F), np.float32))
-        return 4
 
     # -- calibration -------------------------------------------------------
     def calibrate(self, windows, quantile: float = 0.95) -> "LSTMForecaster":
@@ -1032,23 +934,15 @@ class ZooForecaster:
 
     def step(self, x_t, carry):
         """One token for each row of a batched carry: x_t int32 [B].
-        Returns (next token [B] as float32, p_extreme [B], carry)."""
+        Returns (next token [B] as float32, p_extreme [B], carry). The
+        one-session functional API: no serving path calls it (sessions
+        step through the lanes)."""
         self._require_streaming()
         tokens = self._tokens(x_t, (-1,))
         tok, p, carry = self._fns["decode"](self.params, tokens, carry,
                                             *self._tail_args(),
                                             gamma=float(self.gamma))
         return np.asarray(tok, np.float32), np.asarray(p), carry
-
-    def step_many(self, xs, carries, donate=None):
-        """One token for each of N sessions: their batch-1 carries are
-        stacked on the lane axis, stepped in one program and split."""
-        stacked = _lane_map(lambda ax, *rows: jnp.concatenate(rows, ax),
-                            *carries)
-        tok, p, out = self.step(xs, stacked)
-        split = [_lane_map(lambda ax, a, i=i: jax.lax.slice_in_dim(
-            a, i, i + 1, axis=ax), out) for i in range(len(carries))]
-        return tok, p, split
 
     # -- decode lanes ------------------------------------------------------
     def init_slots(self, num_slots: int) -> DecodeSlots:

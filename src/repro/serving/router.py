@@ -382,7 +382,10 @@ class ShardedServingEngine:
         mesh's router, so a client's carry lives on the shard its
         requests are routed to. The cache is kept in membership sync:
         ``add_shard``/``remove_shard`` on this engine migrate its
-        sessions along with the routing."""
+        sessions along with the routing. A ``RecurrentSessionRunner``
+        over it holds its sessions in decode lanes: spill them
+        (``runner.spill_all()``) before a membership change, so every
+        carry is in the cache when it migrates."""
         from repro.serving.sessions import ShardedSessionCache
 
         cache = ShardedSessionCache(router=self.router, **kwargs)

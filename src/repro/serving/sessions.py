@@ -2,24 +2,20 @@
 requests so a streaming step is O(1) instead of O(window).
 
 ``SessionCache`` is model-agnostic (it stores opaque carries with byte
-accounting); ``RecurrentSessionRunner`` binds it to a forecaster that
-exposes ``init_carry`` / ``step`` / ``replay``. For forecasters that
-expose the device-resident slot lifecycle (``init_slots`` /
-``prefill`` / ``insert`` / ``generate``), the runner IS a slot
-allocator: sessions live in fixed device lanes between steps, and a
-batched ``step_many`` is "ensure resident → one fused generate dispatch
-→ read only the requested rows" — the cache is demoted to a *spill
-tier* that holds carries only for sessions LRU-evicted off the lanes
-(or spilled for migration), bitwise-identical on reload. Forecasters
-without slot support keep the PR-5 gather/scatter path: carries
-gathered from the cache, advanced in one fused dispatch per decode-lane
-chunk, and scattered back. Both are bitwise-equal to stepping each
-session alone. Eviction is LRU with an optional TTL and byte budget. A
-cache miss replays the client's window prefix through the same compiled
-step function the hot path uses, so — provided the client supplies its
-history on a miss — eviction never changes the numbers a client sees,
-only the latency. Misses without history start a fresh session from
-zero state (or raise, with ``on_miss="error"``).
+accounting); ``RecurrentSessionRunner`` binds it to a forecaster with
+the device-resident lane lifecycle (``init_slots`` / ``prefill`` /
+``insert`` / ``generate`` / ``extract``, the ``StreamingForecaster``
+protocol). The runner IS a lane allocator: sessions live in fixed
+device lanes between steps, and a batched ``step_many`` is "ensure
+resident → one fused generate dispatch → read only the requested rows"
+— the cache is the *spill tier* that holds carries only for sessions
+LRU-evicted off the lanes (or spilled for migration), bitwise-identical
+on reload. Eviction is LRU with an optional TTL and byte budget. A
+miss replays the client's window prefix through the same compiled step
+the lanes run, so — provided the client supplies its history on a miss
+— eviction never changes the numbers a client sees, only the latency.
+Misses without history start a fresh session from zero state (or
+raise, with ``on_miss="error"``).
 
 ``ShardedSessionCache`` splits the fleet budget over per-shard
 ``SessionCache`` instances keyed by a consistent hash of the client id
@@ -53,7 +49,8 @@ from collections import OrderedDict
 from typing import Any
 
 from repro.obs import trace as obs_trace
-from repro.serving.forecaster import input_spec, streams
+from repro.serving.forecaster import (_donate_default, input_spec,
+                                      streams)
 from repro.serving.router import ConsistentRouter
 from repro.serving.telemetry import Telemetry
 
@@ -500,7 +497,7 @@ DEFAULT_NUM_SLOTS = 64               # lanes per runner when unspecified
 class RecurrentSessionRunner:
     """Streaming serving for a recurrent forecaster: each client is a
     session whose state lives in a device-resident decode lane between
-    requests (slot forecasters) or in the session cache (others).
+    requests.
 
     ``forecaster`` may be the forecaster itself or a zero-arg provider
     returning the *current* forecaster (e.g. ``lambda: registry.get(key)``)
@@ -511,63 +508,44 @@ class RecurrentSessionRunner:
     carrying the live hidden state across (valid shapes: swapped versions
     share the config) — instead of dropping the session.
 
-    With slots (``num_slots`` > 0, the default when the forecaster
-    supports it), the runner is the slot ALLOCATOR: an LRU over lanes
-    decides which sessions stay device-resident; a session that loses
-    its lane spills its carry to the cache (the spill tier) and reloads
-    bitwise-identically on its next step; lanes idle past the cache's
-    TTL are expired like cache entries. ``num_slots=0`` disables slots
-    and keeps the gather/scatter path.
+    The runner is the lane ALLOCATOR over ``num_slots`` lanes (at least
+    one): an LRU over lanes decides which sessions stay device-resident;
+    a session that loses its lane spills its carry to the cache (the
+    spill tier) and reloads bitwise-identically on its next step; lanes
+    idle past the cache's TTL are expired like cache entries. Over a
+    ``ShardedSessionCache`` (``mesh.session_cache()``) the sessions
+    still live in lanes, which membership changes cannot see: the caller
+    spills them (``spill_all()``) before adding or removing a shard, so
+    the cache migrates every carry.
     """
 
     def __init__(self, forecaster, cache: SessionCache | None = None,
                  on_miss: str = "zeros",
-                 donate_carries: bool | None = None,
-                 num_slots: int | None = None):
+                 num_slots: int = DEFAULT_NUM_SLOTS):
         if callable(forecaster) and not hasattr(forecaster, "step"):
             self._provider = forecaster
         else:
             self._provider = None
             self.forecaster = forecaster
-        # donate_carries: the fused programs consume carry buffers in
-        # place (slot state for generate/insert, cached carries for the
-        # gather/scatter path). None resolves to the platform default:
-        # ON off-CPU, off on CPU (where XLA donation is a warn + copy).
-        # ONLY safe when this runner's state is touched by a single
-        # thread during serving — the engine-internal runner qualifies
-        # (one worker flushes, exports happen after drain); a cache
-        # shared with concurrent readers (live-membership migration)
-        # must pass False — the transport workers do.
-        if donate_carries is None:
-            from repro.serving.forecaster import _donate_default
-            donate_carries = _donate_default()
-        self.donate_carries = bool(donate_carries)
         self.last_step_slots = 0     # lane slots of the last step_many
         fc = self._resolve()
         if on_miss not in ("zeros", "error"):
             raise ValueError("on_miss must be 'zeros' or 'error'")
+        if not (hasattr(fc, "init_slots") and streams(fc)):
+            raise TypeError(
+                f"forecaster {type(fc).__name__} does not stream through "
+                f"decode lanes (missing init_slots)")
+        if num_slots < 1:
+            raise TypeError(f"a session runner needs at least one decode "
+                            f"lane, got num_slots={num_slots}")
         self.cache = cache if cache is not None else SessionCache()
         self.on_miss = on_miss
         self._nbytes = fc.carry_nbytes(1)
         self.reprimes = 0            # carries replayed onto new weights
         self.carried_across_swap = 0  # carries reused without history
         # -- device-resident decode slots --------------------------------
-        slot_capable = hasattr(fc, "init_slots") and streams(fc)
-        if num_slots is None:
-            # a ShardedSessionCache's contract is that carries live in
-            # per-shard caches and FOLLOW mesh membership — runner-local
-            # lanes would hide sessions from that migration, so slots
-            # default off over sharded caches (pass num_slots explicitly
-            # to opt in; you then own spilling around membership ops)
-            sharded = isinstance(self.cache, ShardedSessionCache)
-            num_slots = DEFAULT_NUM_SLOTS \
-                if (slot_capable and not sharded) else 0
-        if num_slots and not slot_capable:
-            raise TypeError(
-                f"forecaster {type(fc).__name__} does not support "
-                f"decode slots (missing init_slots); pass num_slots=0")
-        self._slots = fc.init_slots(num_slots) if num_slots else None
-        self.num_slots = self._slots.num_slots if self._slots else 0
+        self._slots = fc.init_slots(num_slots)
+        self.num_slots = self._slots.num_slots
         self._slots_lock = threading.Lock()
         self._lanes: OrderedDict[str, int] = OrderedDict()  # cid -> lane
         self._free = list(reversed(range(self.num_slots)))  # pop() -> 0..
@@ -592,10 +570,9 @@ class RecurrentSessionRunner:
             # serving path — otherwise the first cache miss / swap
             # re-prime pays the jit compile at serve time
             fc.replay(np.zeros((1, window, fc.feature_dim), np.float32))
-        if self._slots is not None:
-            # same deal for the slot lifecycle programs: the first
-            # flush must not pay the generate compile at serve time
-            fc.warm_slots(self.num_slots)
+        # same deal for the lane programs: the first flush must not pay
+        # the generate compile at serve time
+        fc.warm_slots(self.num_slots)
 
     def _resolve(self):
         fc = self._provider() if self._provider is not None \
@@ -658,53 +635,32 @@ class RecurrentSessionRunner:
 
     def step(self, client_id: str, x_t, history=None):
         """One streaming step for ``client_id``. ``x_t`` is one feature
-        vector [F] (or [1, F]). On a cache miss the carry is rebuilt from
+        vector [F] (or [1, F]). On a miss the carry is rebuilt from
         ``history`` ([T, F] window prefix, replayed through the same
-        compiled step the hot path uses). Without history, a miss starts
+        compiled step the lanes run). Without history, a miss starts
         a fresh zero-state session — correct for a new client, but an
         evicted client's forecasts silently restart from scratch, so
         deployments where eviction is expected should pass history or
         construct the runner with ``on_miss="error"``.
         Returns (forecast, p_extreme) scalars."""
-        import numpy as np
-
-        if self._slots is not None:
-            # slot runners have no out-of-lane step path: a lane-resident
-            # session stepped outside its lane would fork its state
-            return self.step_many([(client_id, x_t, history)])[0]
-        fc = self._resolve()
-        version = getattr(fc, "version", 0)
-        shape, dtype = input_spec(fc)
-        x_t = np.asarray(x_t, dtype).reshape((1,) + shape)
-        hist = self._clamp_history(fc, history)
-        carry, stamp = self._resolve_carry(fc, client_id, hist, version)
-        y, p, carry = fc.step(x_t, carry)
-        self.cache.put(client_id, carry, self._nbytes, version=stamp)
-        return float(y[0]), float(p[0])
+        return self.step_many([(client_id, x_t, history)])[0]
 
     def step_many(self, items):
         """Batched streaming step: ``items`` is a list of
-        ``(client_id, x_t, history)`` tuples (history may be None). All
-        sessions step in ONE fused dispatch per decode-lane chunk
-        (``forecaster.step_many``) instead of one dispatch per client —
-        carries are gathered from the cache, stepped stacked, and
-        scattered back, bitwise-identical to calling ``step`` per item
-        (the lane runs every path at one fixed batch width).
+        ``(client_id, x_t, history)`` tuples (history may be None). Every
+        session is made lane-resident, then all of them step in ONE
+        fused generate over the lane state (chunked only when a wave
+        holds more distinct clients than there are lanes), bitwise-
+        identical to calling ``step`` per item.
 
         Duplicate client ids are legal: later occurrences run in a
         follow-up wave so each step sees the carry its predecessor
         wrote, preserving per-client stream order. Returns
-        ``[(forecast, p_extreme), ...]`` in item order. Requires the
-        forecaster to expose ``step_many``; per-session ``step`` is the
-        fallback."""
-        import numpy as np
-
+        ``[(forecast, p_extreme), ...]`` in item order."""
         fc = self._resolve()
-        self.last_step_slots = len(items)
+        self.last_step_slots = 0
         if not items:
             return []
-        if not hasattr(fc, "step_many"):
-            return [self.step(cid, x_t, history=h) for cid, x_t, h in items]
         version = getattr(fc, "version", 0)
         results: list = [None] * len(items)
         # waves: index items so one client's steps never share a batch
@@ -716,36 +672,22 @@ class RecurrentSessionRunner:
             if wave == len(waves):
                 waves.append([])
             waves[wave].append(idx)
-        if self._slots is not None:
-            # slot path: every wave is one fused generate over the full
-            # slot state (chunked only when a wave holds more distinct
-            # clients than there are lanes)
-            S = self.num_slots
-            n_chunks = 0
-            with obs_trace.span("repro.session_step_many"):
-                with self._slots_lock:
-                    self._expire_lanes_locked(fc)
-                    for wave in waves:
-                        for lo in range(0, len(wave), S):
-                            n_chunks += 1
-                            self._generate_chunk_locked(
-                                fc, items, wave[lo:lo + S], version,
-                                results)
-                    tel = self.cache.telemetry
-                    if tel is not None:
-                        tel.record_slots(active=len(self._lanes), lanes=S)
-            self.last_step_slots = n_chunks * S
-            return results
-        # decode-lane slots this call dispatches (each wave pads to the
-        # lane width, chunking beyond it) — the engine reads this for
-        # its occupancy telemetry, so the accounting lives with the
-        # dispatch decision instead of being re-derived
-        width = getattr(fc, "decode_width", None)
-        self.last_step_slots = sum(
-            (-(-len(w) // width) * width) if width else len(w)
-            for w in waves)
+        S = self.num_slots
+        n_chunks = 0
         with obs_trace.span("repro.session_step_many"):
-            self._run_waves(fc, items, waves, version, results)
+            with self._slots_lock:
+                self._expire_lanes_locked(fc)
+                for wave in waves:
+                    for lo in range(0, len(wave), S):
+                        n_chunks += 1
+                        self._generate_chunk_locked(
+                            fc, items, wave[lo:lo + S], version, results)
+                tel = self.cache.telemetry
+                if tel is not None:
+                    tel.record_slots(active=len(self._lanes), lanes=S)
+        # lane slots this call dispatched: the engine's occupancy
+        # telemetry reads it
+        self.last_step_slots = n_chunks * S
         return results
 
     # -- slot allocator ----------------------------------------------------
@@ -829,10 +771,9 @@ class RecurrentSessionRunner:
 
     def _guarded_insert(self, fc, lane, carry) -> None:
         try:
-            fc.insert(self._slots, lane, carry,
-                      donate=self.donate_carries)
+            fc.insert(self._slots, lane, carry)
         except Exception:
-            if self.donate_carries:
+            if _donate_default():
                 self._reset_slots_locked(fc)
             raise
 
@@ -860,10 +801,9 @@ class RecurrentSessionRunner:
             xs[lane] = np.asarray(x_t, dtype).reshape(shape)
             lanes.append(lane)
         try:
-            ys, ps, _ = fc.generate(self._slots, xs, lanes=lanes,
-                                    donate=self.donate_carries)
+            ys, ps, _ = fc.generate(self._slots, xs, lanes=lanes)
         except Exception:
-            if self.donate_carries:
+            if _donate_default():
                 # the donating generate may have consumed the slot
                 # state before failing — poisoned lanes would corrupt
                 # every resident session, so reset the whole plane
@@ -881,8 +821,6 @@ class RecurrentSessionRunner:
         into the cache — the migration/export path: after a spill the
         cache's ``export`` sees every session, carries bitwise-identical
         to the lane state. Returns the number of sessions spilled."""
-        if self._slots is None:
-            return 0
         fc = self._resolve()
         if isinstance(client_ids, str):
             client_ids = [client_ids]
@@ -930,37 +868,3 @@ class RecurrentSessionRunner:
                     "kv_tokens_read": self.kv_tokens_read,
                     "ssm_inplace_waves": self.ssm_inplace_waves,
                     "lane_state_bytes": dict(self.lane_state_bytes)}
-
-    def _run_waves(self, fc, items, waves, version, results) -> None:
-        import numpy as np
-
-        shape, dtype = input_spec(fc)
-        for wave in waves:
-            xs = np.zeros((len(wave),) + shape, dtype)
-            carries, stamps = [], []
-            for row, idx in enumerate(wave):
-                cid, x_t, history = items[idx]
-                xs[row] = np.asarray(x_t, dtype).reshape(shape)
-                hist = self._clamp_history(fc, history)
-                carry, stamp = self._resolve_carry(fc, cid, hist, version)
-                carries.append(carry)
-                stamps.append(stamp)
-            try:
-                ys, ps, new_carries = fc.step_many(
-                    xs, carries, donate=self.donate_carries)
-            except Exception:
-                if self.donate_carries:
-                    # the fused program may have consumed some of the
-                    # donated carry buffers before failing — a cache
-                    # entry pointing at a deleted buffer would poison
-                    # every later step for that client. Drop the wave's
-                    # sessions instead: clients re-prime from history
-                    # (or zeros) on their next step.
-                    for idx in wave:
-                        self.cache.drop(items[idx][0])
-                raise
-            for row, idx in enumerate(wave):
-                cid = items[idx][0]
-                self.cache.put(cid, new_carries[row], self._nbytes,
-                               version=stamps[row])
-                results[idx] = (float(ys[row]), float(ps[row]))

@@ -221,13 +221,9 @@ class _ShardState:
         self.telemetry = Telemetry()
         self.cache = SessionCache(max_sessions=max_sessions,
                                   telemetry=self.telemetry)
-        # donate_carries=False: the recv loop extracts/restores session
-        # carries (migration) concurrently with the flush thread's
-        # batched steps, so in-place carry consumption is not safe here
         self.shard = EngineShard(self.registry, config, self.telemetry,
                                  shard_id=shard_id,
-                                 session_cache=self.cache,
-                                 donate_carries=False)
+                                 session_cache=self.cache)
         if self.state_dir:
             # a cold worker restart on this host comes back with the
             # store's last good weights before the router re-adopts it;

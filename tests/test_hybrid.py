@@ -203,18 +203,20 @@ def test_runner_counts_lane_state_and_kv_reads(model):
 
 
 def test_runner_without_lanes_steps_as_the_lanes_do(model):
-    """With no decode lanes the runner gathers the sessions' carries and
-    steps them in one decode program (``step_many``); its answers are the
-    lanes' answers."""
+    """A session that does not keep a lane steps as a resident one does:
+    three sessions churning through ONE lane (each step spills the
+    lane's session to the host and inserts the next) answer as three
+    sessions resident in three lanes."""
     cfg, params = model
     hist, steps = _sessions(cfg, (6, 11, 9), 3, seed=5)
     answers = []
-    for num_slots in (3, 0):
+    for num_slots in (3, 1):
         fc = ZooForecaster(cfg=cfg, params=params, window=32)
         runner = RecurrentSessionRunner(fc, num_slots=num_slots)
         assert runner.num_slots == num_slots
         answers.append([runner.step_many([
             (c, steps[c][t], hist[c] if t == 0 else None)
             for c in sorted(hist)]) for t in range(3)])
+    assert runner.slot_stats()["spills"] > 0
     np.testing.assert_allclose(np.asarray(answers[0]),
                                np.asarray(answers[1]), rtol=0, atol=1e-5)

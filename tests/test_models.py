@@ -30,31 +30,6 @@ def test_rnn_no_evl_head():
     assert u is None
 
 
-def test_stack_split_rnn_carries_roundtrip():
-    from repro.models.rnn import (init_rnn_carry, split_rnn_carry,
-                                  stack_rnn_carries)
-    cfg = RNNConfig(hidden=16, num_layers=2)
-    params = init_rnn(jax.random.PRNGKey(1), cfg)
-    rng = np.random.default_rng(0)
-    singles = []
-    for i in range(3):
-        c = init_rnn_carry(params, 1)
-        singles.append(tuple(
-            (h + i, cc - i) for h, cc in c))          # distinct values
-    stacked = stack_rnn_carries(singles, pad_to=8)
-    assert stacked[0][0].shape == (8, 16)
-    back = split_rnn_carry(stacked, n=3)
-    for want, got in zip(singles, back):
-        for (h1, c1), (h2, c2) in zip(want, got):
-            np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
-            np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
-    # padding rows are zeros; over-tight pad_to raises
-    np.testing.assert_array_equal(np.asarray(stacked[0][0][3:]),
-                                  np.zeros((5, 16), np.float32))
-    with pytest.raises(ValueError):
-        stack_rnn_carries(singles, pad_to=2)
-
-
 @given(st.integers(1, 3), st.integers(16, 64))
 @settings(max_examples=10, deadline=None)
 def test_blocked_attention_matches_reference(b, s):

@@ -4,7 +4,6 @@ boundary), dispatch accounting ("one fused dispatch per flush" asserted,
 not trusted), telemetry time-series/history, and the metrics export
 surface (Prometheus text, JSONL events, the stdlib HTTP endpoint)."""
 
-import dataclasses
 import json
 import time
 import urllib.request
@@ -186,17 +185,16 @@ def test_dispatch_counting_inactive_is_default():
 
 
 def test_forecaster_dispatch_counts(forecaster):
-    """The performance claims of PRs 4-5, asserted: a batched step_many
-    is ONE fused dispatch per decode-lane chunk, a replay is ONE scan
-    dispatch, a predict is ONE fused dispatch."""
-    W = forecaster.decode_width
-    carries = [forecaster.init_carry(1) for _ in range(W)]
-    xs = np.zeros((W, CFG.input_dim), np.float32)
-    forecaster.step_many(xs, carries)                    # warm
+    """The performance claims of PRs 4-8, asserted: a generate over a
+    full lane set is ONE fused dispatch, a replay is ONE scan dispatch,
+    a predict is ONE fused dispatch."""
+    slots = forecaster.init_slots(forecaster.decode_width)
+    xs = np.zeros((slots.num_slots, CFG.input_dim), np.float32)
+    lanes = list(range(slots.num_slots))
+    forecaster.generate(slots, xs, lanes=lanes)          # warm
     with dispatch.counting() as counts:
-        forecaster.step_many(xs, [forecaster.init_carry(1)
-                                  for _ in range(W)])
-    assert counts["decode_many"] == 1                    # one lane chunk
+        forecaster.generate(slots, xs, lanes=lanes)
+    assert counts["slots_generate"] == 1                 # every lane at once
     assert counts.total() == 1                           # and nothing else
 
     window = np.zeros((1, CFG.window, CFG.input_dim), np.float32)
@@ -214,8 +212,8 @@ def test_forecaster_dispatch_counts(forecaster):
 def test_engine_step_flush_is_one_fused_dispatch(registry):
     """Tier-1 guard on the slots decode path (ISSUE 8): once sessions
     are lane-resident, a step flush is exactly ONE fused
-    ``slots_generate`` dispatch — ZERO host gather/scatter ops
-    (``decode_many``), zero per-session steps, zero inserts."""
+    ``slots_generate`` dispatch — zero per-session steps, zero
+    inserts."""
     clients = [f"client-{i}" for i in range(BCFG.max_batch)]
     x = np.zeros(CFG.input_dim, np.float32)
     with ServingEngine(registry, BCFG) as eng:
@@ -232,29 +230,10 @@ def test_engine_step_flush_is_one_fused_dispatch(registry):
         flushes = eng.telemetry.step_batches - flushes_before
     assert flushes >= 1
     assert counts["slots_generate"] == flushes
-    assert counts["decode_many"] == 0       # no host gather/scatter
     assert counts["decode_step"] == 0       # nothing went per-session
     assert counts["slots_insert"] == 0      # no lane churn at steady state
     assert counts["decode_replay"] == 0     # no cache miss hit replay
     assert counts.total() == flushes        # and nothing else at all
-
-
-def test_engine_step_gather_scatter_path_when_slots_disabled(registry):
-    """decode_slots=0 keeps the PR-5 gather/scatter contract: one
-    decode_many dispatch per flush wave."""
-    cfg = dataclasses.replace(BCFG, decode_slots=0)
-    with ServingEngine(registry, cfg) as eng:
-        eng.warmup("m", lengths=(CFG.window,))
-        with dispatch.counting() as counts:
-            futs = [eng.submit_step("m", f"gs-{i}",
-                                    np.zeros(CFG.input_dim, np.float32))
-                    for i in range(cfg.max_batch)]
-            for f in futs:
-                f.result(timeout=10.0)
-    flushes = eng.telemetry.step_batches
-    assert flushes >= 1
-    assert counts["decode_many"] == flushes
-    assert counts["slots_generate"] == 0
 
 
 # -- traces through the serving stack --------------------------------------

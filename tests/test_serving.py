@@ -141,13 +141,22 @@ def test_overlong_window_clamps_to_largest_bucket(registry, forecaster):
     # every length the hot path can see maps into the configured buckets
     assert {cfg.bucket_len(t) for t in (1, 12, 19, 20, 21, 64)} <= {12, 20}
     long_window = _windows(1, t=33, seed=9)[0]
+    longer = _windows(1, t=64, seed=10)[0]
     with ServingEngine(registry, cfg) as eng:
         eng.warmup("m", lengths=(12, 20))
         y_got, p_got = eng.predict("m", long_window, timeout=10.0)
+        y_sub, p_sub = eng.submit("m", longer).result(timeout=10.0)
     # the served result is exactly the truncated-window prediction
     y_ref, p_ref = forecaster.predict(long_window[None, -20:])
     np.testing.assert_array_equal(y_got, y_ref[0])
     np.testing.assert_array_equal(p_got, p_ref[0])
+    # ``submit`` of a payload three times the largest bucket serves its
+    # newest 20 rows, not its oldest
+    y_new, p_new = forecaster.predict(longer[None, -20:])
+    np.testing.assert_array_equal(y_sub, y_new[0])
+    np.testing.assert_array_equal(p_sub, p_new[0])
+    y_old, _ = forecaster.predict(longer[None, :20])
+    assert y_sub != y_old[0]
 
 
 def test_client_id_threads_through_to_telemetry(registry):
@@ -267,9 +276,9 @@ def test_compiled_rnn_builds_once_under_threads():
 # -- batched decode path ---------------------------------------------------
 
 def test_batched_step_matches_sequential_bitwise(forecaster):
-    """The decode-lane contract: stepping N sessions as one batched
-    flush is BITWISE identical to stepping them one by one (both run
-    the same fixed-width compiled step)."""
+    """The decode-lane contract: N sessions stepped in ONE generate over
+    N lanes are BITWISE the N per-session ``step`` loops (both run the
+    same fixed-width lane step)."""
     n, T = 8, CFG.window
     rng = np.random.default_rng(21)
     xs = rng.standard_normal((T, n, 5)).astype(np.float32) * 0.02
@@ -280,12 +289,17 @@ def test_batched_step_matches_sequential_bitwise(forecaster):
         for i in range(n):
             y, p, seq[i] = forecaster.step(xs[t, i:i + 1], seq[i])
             seq_out[i] = (float(y[0]), float(p[0]))
-    bat = [forecaster.init_carry(1) for _ in range(n)]
+    slots = forecaster.init_slots(n)
+    assert slots.num_slots == n
+    for i in range(n):
+        forecaster.insert(slots, i, forecaster.init_carry(1))
     for t in range(T):
-        ys, ps, bat = forecaster.step_many(xs[t], bat)
+        ys, ps, slots = forecaster.generate(slots, xs[t],
+                                            lanes=list(range(n)))
     for i in range(n):
         assert (float(ys[i]), float(ps[i])) == seq_out[i]
-        for (h1, c1), (h2, c2) in zip(seq[i], bat[i]):
+        lane = forecaster.extract(slots, i)
+        for (h1, c1), (h2, c2) in zip(seq[i], lane):
             np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
             np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
 
@@ -303,8 +317,8 @@ def test_donate_default_platform_gate(monkeypatch):
 
 def test_donated_step_many_matches_non_donated_bitwise(forecaster,
                                                        monkeypatch):
-    """The donating compiled programs (gather/scatter and slots paths)
-    must be bit-for-bit the non-donating ones.  On CPU an explicit
+    """The donating lane programs (insert and generate) must be
+    bit-for-bit the non-donating ones.  On CPU an explicit
     ``donate=True`` is gated off, so force the donating variants by
     patching the platform query — XLA then warns and copies, which is
     exactly the behavior the gate exists to avoid, but the numerics
@@ -322,14 +336,6 @@ def test_donated_step_many_matches_non_donated_bitwise(forecaster,
 
     rng = np.random.default_rng(3)
     xs = rng.standard_normal((5, 5)).astype(np.float32) * 0.02
-    mk = lambda: [forecaster.init_carry(1) for _ in range(5)]  # noqa: E731
-    y0, p0, cs0 = forecaster.step_many(xs, mk(), donate=False)
-    y1, p1, cs1 = donated(forecaster.step_many, xs, mk())
-    assert np.array_equal(y0, y1) and np.array_equal(p0, p1)
-    for a, b in zip(jax.tree_util.tree_leaves(cs0),
-                    jax.tree_util.tree_leaves(cs1)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
     carry = forecaster.init_carry(1)
     s0 = forecaster.insert(forecaster.init_slots(4), 1, carry,
                            donate=False)
@@ -344,35 +350,73 @@ def test_donated_step_many_matches_non_donated_bitwise(forecaster,
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_transport_worker_shard_forces_donation_off():
-    """Regression: the transport worker's recv loop migrates session
-    carries concurrently with the flush thread, so its EngineShard must
-    pin donate_carries=False regardless of platform default."""
-    from repro.serving.transport import _ShardState
-    state = _ShardState()
-    state.configure(0, BatcherConfig(max_batch=4, max_wait_ms=1.0), 16)
-    assert state.shard.donate_carries is False
-
-
 def test_step_many_partial_and_chunked_flushes(forecaster):
-    """Batches that underfill (n < width) or overflow (n > width) the
-    decode lane still match per-session steps bitwise."""
+    """A generate that steps some of the lanes (1, 3, 8 or 13 of 16:
+    under one decode-lane chunk, one full chunk, across two) matches
+    per-session steps bitwise, and the lanes it does not step keep
+    their carry."""
     rng = np.random.default_rng(5)
     for n in (1, 3, 8, 13):
-        xs = rng.standard_normal((n, 5)).astype(np.float32) * 0.02
-        ys, ps, _ = forecaster.step_many(
-            xs, [forecaster.init_carry(1) for _ in range(n)])
-        assert ys.shape == (n,)
-        for i in range(n):
-            y1, p1, _ = forecaster.step(xs[i:i + 1],
-                                        forecaster.init_carry(1))
-            assert float(ys[i]) == float(y1[0])
-            assert float(ps[i]) == float(p1[0])
+        slots = forecaster.init_slots(16)
+        assert slots.num_slots == 16
+        lanes = np.sort(rng.permutation(16)[:n])
+        xs = rng.standard_normal((16, 5)).astype(np.float32) * 0.02
+        ys, ps, slots = forecaster.generate(slots, xs, lanes=lanes)
+        for lane in lanes:
+            y1, p1, c1 = forecaster.step(xs[lane:lane + 1],
+                                         forecaster.init_carry(1))
+            assert float(ys[lane]) == float(y1[0])
+            assert float(ps[lane]) == float(p1[0])
+            for a, b in zip(jax.tree_util.tree_leaves(c1),
+                            jax.tree_util.tree_leaves(
+                                forecaster.extract(slots, lane))):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for lane in sorted(set(range(16)) - set(lanes.tolist())):
+            for a in jax.tree_util.tree_leaves(
+                    forecaster.extract(slots, lane)):
+                assert not np.asarray(a).any()     # still the zero carry
+
+
+def test_decode_slots_zero_is_refused():
+    """Every streaming step goes through the decode lanes: an engine
+    config without lanes is refused at construction."""
+    with pytest.raises(ValueError, match="decode_slots must be >= 1"):
+        BatcherConfig(decode_slots=0)
+    assert BatcherConfig(decode_slots=1).decode_slots == 1
+
+
+class _NoLanes:
+    """Streams by the one-session API alone: no decode lanes."""
+
+    window, feature_dim, decode_width, kind = 20, 5, 1, "stub"
+
+    def init_carry(self, batch=1):
+        return ()
+
+    def carry_nbytes(self, batch=1):
+        return 0
+
+    def step(self, x_t, carry):
+        raise AssertionError("never stepped")
+
+    def replay(self, window, carry=None):
+        raise AssertionError("never replayed")
+
+
+@pytest.mark.parametrize("case", ["num_slots=0", "no init_slots"])
+def test_runner_without_lanes_is_refused(forecaster, case):
+    """A session runner steps only through decode lanes: zero lanes, or
+    a forecaster without the lane lifecycle, is a TypeError."""
+    with pytest.raises(TypeError):
+        if case == "num_slots=0":
+            RecurrentSessionRunner(forecaster, num_slots=0)
+        else:
+            RecurrentSessionRunner(_NoLanes())
 
 
 def test_runner_step_many_matches_step(forecaster):
-    """Gather/scatter through the session cache: batched runner steps
-    equal sequential runner steps, carries land back per client."""
+    """Batched runner steps equal sequential runner steps; the sessions
+    stay in their lanes, and a spill hands every carry to the cache."""
     n, T = 6, 10
     rng = np.random.default_rng(9)
     xs = rng.standard_normal((T, n, 5)).astype(np.float32) * 0.02

@@ -160,7 +160,9 @@ def test_mesh_remove_shard_drains_and_rehomes(forecaster):
 def test_mesh_membership_migrates_session_carries(forecaster):
     """Session caches attached via ``session_cache()`` follow membership
     changes: a departing shard's clients keep their carries (migrated to
-    the new owners), unmoved clients are untouched."""
+    the new owners), unmoved clients are untouched. The runner holds
+    its sessions in decode lanes, so it spills them to the cache before
+    the membership change."""
     from repro.serving import RecurrentSessionRunner
 
     with _mesh(forecaster, n_shards=3) as mesh:
@@ -173,6 +175,7 @@ def test_mesh_membership_migrates_session_carries(forecaster):
                 runner.step(f"s{c}", w[c][t])
         owners = {f"s{c}": cache.shard_for(f"s{c}") for c in range(8)}
         victim = owners["s0"]
+        assert runner.spill_all() == 8
         mesh.remove_shard(victim)
         # every session survived the membership change, on its new owner
         for c in range(8):
